@@ -131,6 +131,8 @@ def engine_throughput(n_warps: int = 32, reps: int = 3) -> dict:
 
 
 def main() -> None:
+    from repro.engine import install_jax_cache
+    install_jax_cache()
     s = summary()
     print("== Fig 9 (trace discrepancy vs hardware oracle) ==")
     for k, v in s.items():

@@ -92,6 +92,8 @@ def kernel_timing_rows() -> list[dict]:
 
 
 def main() -> None:
+    from repro.engine import install_jax_cache
+    install_jax_cache()
     print("== divergence-aware tile census (Hanoi EMPTY-tile skipping) ==")
     for r in tile_census_rows():
         print(f"  {r['case']:38s} kept={r['flops_kept_frac']:6.1%} "

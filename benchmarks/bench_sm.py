@@ -29,7 +29,7 @@ import time
 
 from repro.core import MachineConfig
 from repro.core.programs import make_suite
-from repro.engine import Simulator
+from repro.engine import Simulator, install_jax_cache
 from repro.engine.types import SimRequest
 from repro.timing.policies import POLICY_NAMES
 
@@ -154,6 +154,7 @@ def main() -> None:
                     help="sm_jax gate: bit-equal SM traces + >=10x speedup")
     ap.add_argument("--smoke-warps", type=int, default=8)
     args = ap.parse_args()
+    install_jax_cache()
     if args.smoke:
         res = sm_jax_smoke(n_warps=args.smoke_warps)
         print(f"sm_jax smoke: {res['equality_cells']} equality cells over "

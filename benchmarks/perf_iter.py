@@ -99,7 +99,9 @@ def main() -> None:
     args = ap.parse_args()
 
     import jax
+    from repro.engine import install_jax_cache
     from repro.launch.dryrun import run_cell
+    install_jax_cache()
 
     os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
     results = []

@@ -55,6 +55,8 @@ def main(argv: list[str] | None = None) -> None:
                     help="run only the repro.engine end-to-end smoke "
                          "(tiny compare() call; used by CI)")
     args = ap.parse_args(argv)
+    from repro.engine import install_jax_cache
+    install_jax_cache()
 
     t_all = time.perf_counter()
     rows: list[tuple[str, float, str]] = []

@@ -31,10 +31,12 @@ any mechanism by name:
    issue scheduling) as one ``jit(vmap)`` lane-parallel device program —
    and check it is bit-identical to the Python interleaver, with JIT
    compilation metered separately from execution wall time;
-10. scale out: a 2-process service (``procs=2`` — signature-affine shard
-    routing, numpy groups chunked across shards) warmed from a persistent
-    compile cache (``warm_start=``), then restarted to prove the
-    zero-re-trace contract from its own cache counters;
+10. scale out: a 2-process service (``procs=2`` — jax work on the one
+    device-owning shard, numpy groups chunked across shards) warmed from a
+    persistent compile cache (``warm_start=``), then restarted to prove the
+    zero-re-trace contract from its own cache counters.  It runs first,
+    before this process touches jax, because a chip belongs to one process
+    at a time;
 11. statically verify programs without running them (``repro.analysis``):
     lint the Fig 6 ablation (its missing BREAK is a ``reconvergence``
     error), watch the service reject it at admission with the full
@@ -51,13 +53,62 @@ import tempfile
 from repro.core import MachineConfig, disassemble
 from repro.core.programs import (fig6_program, make_suite,
                                  spinlock_no_yield_program, spinlock_program)
-from repro.engine import RotatingJsonlSink, Simulator, SimStatus
+from repro.engine import (RotatingJsonlSink, Simulator, SimStatus,
+                          install_jax_cache)
+
+
+def process_tier(CFG, sim):
+    """Section 10 — runs before anything else touches jax.
+
+    Numpy mechanisms serialize behind the GIL; procs=2 spawns two shard
+    processes and chunks homogeneous numpy groups across them, while every
+    jax group runs on shard 0, the one process that owns the device.  A
+    chip belongs to one process at a time, so the parent must not hold it
+    when the shards start: ``main`` calls this first.  The warm_start
+    directory persists compile work: a restarted service replays the
+    manifest before admitting traffic, so hot signatures never re-trace.
+    """
+    from repro.engine import as_request
+    from repro.service import SimulationService
+
+    benches = [b for b in make_suite(CFG, datasets=1)
+               if b.name in ("HOTS0", "GAUS0", "RBFS0", "DIAMOND")]
+    warm_dir = tempfile.mkdtemp(prefix="repro-quickstart-cache-")
+    reqs = [as_request(b, CFG) for b in benches[:4]]
+    with SimulationService(default_mechanism="hanoi", procs=2,
+                           warm_start=warm_dir) as svc:
+        out = svc.run(reqs, timeout=300)                 # chunked across shards
+        jx = svc.run(reqs[:2], mechanism="hanoi_jax", timeout=600)  # shard 0
+        st = svc.stats()
+    print("\n=== process tier: 2 shards, jax work on the device shard ===")
+    shard_of = lambda r: r.meta["service"]["shard"]
+    print(f"numpy group spread over shards {sorted({shard_of(r) for r in out})}; "
+          f"jax group on device shard {shard_of(jx[0])}")
+    assert {shard_of(r) for r in jx} == {0}
+    print(f"shards: " + " ".join(f"s{s.shard}(pid {s.pid}): {s.completed} ok"
+                                 for s in st.shards))
+    print(f"compile cache: {st.cache_misses} trace(s) recorded -> {warm_dir}")
+    assert all(a.status == b.status for a, b in
+               zip(out, (sim.run(r) for r in reqs)))
+
+    # restart: the warmed service serves the same jax signature with ZERO
+    # serve-time re-traces (the deserialized AOT executable)
+    with SimulationService(default_mechanism="hanoi_jax", procs=2,
+                           warm_start=warm_dir) as svc:
+        svc.run(reqs[:2], timeout=600)
+        st2 = svc.stats()
+    print(f"warm restart: {st2.warm_signatures} sig(s) warmed "
+          f"({st2.warm_loaded} deserialized, {st2.warm_retraced} re-traced), "
+          f"serve-time traces={st2.cache_misses}")
+    assert st2.cache_misses == 0                         # zero re-trace contract
 
 
 def main():
     W = 8
     CFG = MachineConfig(n_threads=W, max_steps=40_000)
     sim = Simulator("hanoi")
+    install_jax_cache()
+    process_tier(CFG, sim)          # section 10, before this process uses jax
 
     # --- 1. spinlock: pre-Volta deadlock vs Hanoi ------------------------------
     prog = spinlock_program()
@@ -223,42 +274,6 @@ def main():
     assert jax_cell.cycles == py_cell.cycles
     assert jax_cell.stall_breakdown == py_cell.stall_breakdown
     assert jax_cell.mechanism == "sm_jax"
-
-    # --- 10. process tier: 2 shard processes + a warmed compile cache -----------
-    # Numpy mechanisms serialize behind the GIL; procs=2 spawns two shard
-    # processes and chunks homogeneous numpy groups across them, while jax
-    # groups stay affine to one shard (executable-cache locality).  The
-    # warm_start directory persists compile work: a restarted service replays
-    # the manifest before admitting traffic, so hot signatures never re-trace.
-    from repro.engine import as_request
-
-    warm_dir = tempfile.mkdtemp(prefix="repro-quickstart-cache-")
-    reqs = [as_request(b, CFG) for b in benches[:4]]
-    with SimulationService(default_mechanism="hanoi", procs=2,
-                           warm_start=warm_dir) as svc:
-        out = svc.run(reqs, timeout=300)                 # chunked across shards
-        jx = svc.run(reqs[:2], mechanism="hanoi_jax", timeout=600)  # affine
-        st = svc.stats()
-    print("\n=== process tier: 2 shards, signature-affine routing ===")
-    shard_of = lambda r: r.meta["service"]["shard"]
-    print(f"numpy group spread over shards {sorted({shard_of(r) for r in out})}; "
-          f"jax group affine to shard {shard_of(jx[0])}")
-    print(f"shards: " + " ".join(f"s{s.shard}(pid {s.pid}): {s.completed} ok"
-                                 for s in st.shards))
-    print(f"compile cache: {st.cache_misses} trace(s) recorded -> {warm_dir}")
-    assert all(a.status == b.status for a, b in
-               zip(out, (sim.run(r) for r in reqs)))
-
-    # restart: the warmed service serves the same jax signature with ZERO
-    # serve-time re-traces (deserialized AOT executable where jaxlib allows)
-    with SimulationService(default_mechanism="hanoi_jax", procs=2,
-                           warm_start=warm_dir) as svc:
-        svc.run(reqs[:2], timeout=600)
-        st2 = svc.stats()
-    print(f"warm restart: {st2.warm_signatures} sig(s) warmed "
-          f"({st2.warm_loaded} deserialized, {st2.warm_retraced} re-traced), "
-          f"serve-time traces={st2.cache_misses}")
-    assert st2.cache_misses == st2.warm_retraced         # zero re-trace contract
 
     # --- 11. static analysis: lint -> admission rejection -> similarity ---------
     from repro.analysis import StaticAnalysisError, analyze_program

@@ -78,8 +78,8 @@ from .types import (SimRequest, SimResult, SimStatus, SmResult,
                     classify_status, worst_status)
 from .simulator import (CompareReport, CompareRow, Simulator, as_request)
 from .compile_cache import (CompileCache, WarmReport, compile_cache_stats,
-                            install_compile_cache, installed_cache,
-                            uninstall_compile_cache)
+                            install_compile_cache, install_jax_cache,
+                            installed_cache, uninstall_compile_cache)
 from . import adapters as _adapters            # registers the built-ins
 from . import mechanisms as _mechanisms        # registers the plugins
 
@@ -91,7 +91,8 @@ __all__ = [
     "WarmReport",
     "as_request", "available_mechanisms", "classify_status",
     "compile_cache_stats", "feed_result",
-    "get_mechanism", "install_compile_cache", "installed_cache",
+    "get_mechanism", "install_compile_cache", "install_jax_cache",
+    "installed_cache",
     "iter_mechanisms", "register_mechanism",
     "replay_payload", "run_meta", "sm_run_meta", "timing_meta",
     "uninstall_compile_cache", "unregister_mechanism",

@@ -23,6 +23,7 @@ Each adapter funnels through :func:`~repro.engine.types.classify_status`, so
 """
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from collections import OrderedDict
@@ -354,16 +355,24 @@ def _compiled_batch_exec(cfg, majority_first: bool, batch: int, pad_len: int):
 
     import jax
     import jax.numpy as jnp
+    from jax._src import config as jax_config
 
     W = cfg.n_threads
     sds = jax.ShapeDtypeStruct
+    # an executable that JAX's persistent compilation cache handed back does
+    # not survive serialize -> deserialize on the CPU backend (its fused
+    # functions are missing at run time), so the executable an installed
+    # cache serializes is compiled fresh
+    fresh = (jax_config.enable_compilation_cache(False) if cache is not None
+             else contextlib.nullcontext())
     t0 = time.perf_counter()
-    compiled = _jitted_batch_runner(cfg, majority_first).lower(
-        sds((batch, pad_len, 8), jnp.int32),
-        sds((batch, pad_len), jnp.bool_),
-        sds((batch, W, cfg.n_regs), jnp.int32),
-        sds((batch, cfg.mem_size), jnp.int32),
-        sds((batch, W), jnp.int32)).compile()
+    with fresh:
+        compiled = _jitted_batch_runner(cfg, majority_first).lower(
+            sds((batch, pad_len, 8), jnp.int32),
+            sds((batch, pad_len), jnp.bool_),
+            sds((batch, W, cfg.n_regs), jnp.int32),
+            sds((batch, cfg.mem_size), jnp.int32),
+            sds((batch, W), jnp.int32)).compile()
     compile_s = time.perf_counter() - t0
     with _BATCH_CACHE_LOCK:
         _BATCH_STATS["misses"] += 1

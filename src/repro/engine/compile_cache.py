@@ -10,10 +10,12 @@ module makes that state durable:
   pad-class, batch-class) key and its observed compile time.  The manifest
   is the durable record of *what was hot*; replaying it re-traces each
   signature before a restarted worker admits traffic.
-* **Serialized AOT executables** — where the installed jaxlib supports
-  ``jax.experimental.serialize_executable``, the compiled executable itself
-  is pickled under ``{dir}/execs/``, so warming (and cold misses at serve
-  time) deserialize instead of re-tracing at all.
+* **Serialized AOT executables** — the compiled executable itself is
+  serialized (``jax.experimental.serialize_executable``) under
+  ``{dir}/execs/``, so warming (and cold misses at serve time) deserialize
+  instead of re-tracing at all.  Entries are keyed by the backend platform,
+  device kind and jax version as well as the signature: an executable
+  compiled for another backend is a miss, never a load error.
 
 Both layers are written atomically (tmp file + ``os.replace``) with one
 file per entry, so N shard processes can share one cache directory without
@@ -21,10 +23,11 @@ coordination: concurrent stores of the same signature are idempotent
 last-writer-wins of identical content.
 
 :class:`~repro.service.core.SimulationService` wires this up via its
-``warm_start=`` argument; shards warm only the slice of the manifest whose
-:func:`affinity_token` hashes to them, mirroring the service's
-signature-affine routing so each process re-traces exactly the signatures
-it will serve.
+``warm_start=`` argument; in the process tier only the device-owning shard
+warms, because it is the only one that runs jax work.
+
+:func:`install_jax_cache` is the other half: it places JAX's own persistent
+compilation cache, and every entry point that compiles calls it first.
 
 The module imports no jax at top level — installing a cache keeps
 numpy-only deployments jax-free.
@@ -47,12 +50,12 @@ from repro.core.isa import MachineConfig
 __all__ = [
     "affinity_token", "shard_of_token", "CompileCache", "WarmReport",
     "install_compile_cache", "installed_cache", "uninstall_compile_cache",
-    "compile_cache_stats",
+    "compile_cache_stats", "install_jax_cache",
 ]
 
 
 # ---------------------------------------------------------------------------
-# affinity hashing — shared by service routing and warm-start sharding
+# affinity hashing — shared by service routing and the manifest
 # ---------------------------------------------------------------------------
 
 def _canon_cfg(cfg: MachineConfig) -> str:
@@ -64,10 +67,11 @@ def affinity_token(mechanism: str, cfg: MachineConfig,
     """The stable routing token of one compiled-state locality class.
 
     Everything that shares a token shares jit/executable cache state
-    (mechanism + canonical cfg + scheduling flavor + padding class), so the
-    service routes it to one shard and warm-start replays it there.  The
-    token is plain text — hash it with :func:`shard_of_token`, never with
-    the builtin ``hash`` (randomized per process, useless across a pool).
+    (mechanism + canonical cfg + scheduling flavor + padding class).  The
+    token is plain text and computed without touching jax, so a parent
+    process can route with it — hash it with :func:`shard_of_token`, never
+    with the builtin ``hash`` (randomized per process, useless across a
+    pool).
     """
     return (f"{mechanism}|{_canon_cfg(cfg)}|mf{int(bool(majority_first))}"
             f"|pad{int(pad_len)}")
@@ -81,24 +85,39 @@ def shard_of_token(token: str, n_shards: int) -> int:
 
 
 # ---------------------------------------------------------------------------
-# serialization support probe
+# JAX's persistent compilation cache
 # ---------------------------------------------------------------------------
 
-_SERIALIZE_SUPPORT: bool | None = None
+#: The checkout root: ``src/repro/engine/`` is three levels below it.
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__)))))
 
 
-def supports_serialization() -> bool:
-    """Whether this jaxlib can serialize/deserialize AOT executables."""
-    global _SERIALIZE_SUPPORT
-    if _SERIALIZE_SUPPORT is None:
-        try:
-            from jax.experimental import serialize_executable  # noqa: F401
-            _SERIALIZE_SUPPORT = (
-                hasattr(serialize_executable, "serialize")
-                and hasattr(serialize_executable, "deserialize_and_load"))
-        except Exception:
-            _SERIALIZE_SUPPORT = False
-    return _SERIALIZE_SUPPORT
+def install_jax_cache() -> str:
+    """Place JAX's persistent compilation cache; returns its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and
+    nothing is changed.  Otherwise the cache goes to the fixed
+    ``<checkout>/.jax_cache``: the directory is part of what a later
+    process must find again, so it is never derived from a temp dir, a
+    pid or the time.  Entry points call this before they compile; importing
+    the library never does.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+    directory = os.path.join(_CHECKOUT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", directory)
+    return directory
+
+
+def _backend_key() -> str:
+    """Platform, device kind and jax version of this process's backend —
+    an executable serialized under one of them cannot load under another."""
+    import jax
+    dev = jax.devices()[0]
+    return f"{dev.platform}|{dev.device_kind}|jax{jax.__version__}"
 
 
 # ---------------------------------------------------------------------------
@@ -125,11 +144,9 @@ class CacheEntry:
 
 @dataclass
 class WarmReport:
-    """Outcome of replaying the manifest slice assigned to one shard."""
+    """Outcome of replaying the manifest in one process."""
 
-    shard: int = 0
-    n_shards: int = 1
-    signatures: int = 0     # manifest entries assigned to this shard
+    signatures: int = 0     # manifest entries replayed
     loaded: int = 0         # satisfied by a deserialized AOT executable
     retraced: int = 0       # had to trace+compile from scratch
     errors: int = 0
@@ -192,14 +209,15 @@ class CompileCache:
         return hashlib.sha1(f"{token}|b{int(batch)}"
                             .encode("utf-8")).hexdigest()[:20]
 
-    def _paths(self, mechanism: str, cfg: MachineConfig,
-               majority_first: bool, batch: int, pad_len: int
-               ) -> tuple[str, str, str]:
-        token = affinity_token(mechanism, cfg, majority_first, pad_len)
-        digest = self._digest(token, batch)
-        return (token,
-                os.path.join(self._sig_dir, f"{digest}.json"),
-                os.path.join(self._exec_dir, f"{digest}.jaxexec"))
+    def _sig_path(self, token: str, batch: int) -> str:
+        return os.path.join(self._sig_dir,
+                            f"{self._digest(token, batch)}.json")
+
+    def _exec_path(self, token: str, batch: int) -> str:
+        # the manifest records what was hot on any backend; an executable
+        # only loads on the backend that compiled it, so its key names one
+        digest = self._digest(f"{token}|{_backend_key()}", batch)
+        return os.path.join(self._exec_dir, f"{digest}.jaxexec")
 
     # -- store / load ----------------------------------------------------
 
@@ -207,28 +225,25 @@ class CompileCache:
                          majority_first: bool, batch: int, pad_len: int,
                          compiled: Any, compile_time_s: float | None = None
                          ) -> bool:
-        """Record a fresh compile: always the manifest entry, plus the
-        serialized executable when jaxlib supports it.  Returns whether the
-        executable payload was persisted."""
-        token, sig_path, exec_path = self._paths(
-            mechanism, cfg, majority_first, batch, pad_len)
+        """Record a fresh compile: the manifest entry plus the serialized
+        executable.  Returns whether the executable payload was persisted."""
+        token = affinity_token(mechanism, cfg, majority_first, pad_len)
         entry = {"mechanism": mechanism, "cfg": cfg._asdict(),
                  "majority_first": bool(majority_first), "batch": int(batch),
                  "pad_len": int(pad_len), "token": token,
                  "compile_time_s": float(compile_time_s or 0.0)}
-        _atomic_write(sig_path,
+        _atomic_write(self._sig_path(token, batch),
                       json.dumps(entry, sort_keys=True).encode("utf-8"))
         wrote_exec = False
-        if supports_serialization():
-            try:
-                from jax.experimental import serialize_executable as se
-                payload, in_tree, out_tree = se.serialize(compiled)
-                _atomic_write(exec_path,
-                              pickle.dumps((payload, in_tree, out_tree)))
-                wrote_exec = True
-            except Exception:
-                with self._lock:
-                    self.stats["serialize_failures"] += 1
+        try:
+            from jax.experimental import serialize_executable as se
+            payload, in_tree, out_tree = se.serialize(compiled)
+            _atomic_write(self._exec_path(token, batch),
+                          pickle.dumps((payload, in_tree, out_tree)))
+            wrote_exec = True
+        except Exception:
+            with self._lock:
+                self.stats["serialize_failures"] += 1
         with self._lock:
             self.stats["stored"] += 1
         return wrote_exec
@@ -236,18 +251,16 @@ class CompileCache:
     def has(self, mechanism: str, cfg: MachineConfig, majority_first: bool,
             batch: int, pad_len: int) -> bool:
         """Whether the manifest already records this signature."""
-        _, sig_path, _ = self._paths(mechanism, cfg, majority_first,
-                                     batch, pad_len)
-        return os.path.exists(sig_path)
+        token = affinity_token(mechanism, cfg, majority_first, pad_len)
+        return os.path.exists(self._sig_path(token, batch))
 
     def load_executable(self, mechanism: str, cfg: MachineConfig,
                         majority_first: bool, batch: int, pad_len: int
                         ) -> Any | None:
-        """A deserialized AOT executable for the signature, or ``None``."""
-        if not supports_serialization():
-            return None
-        _, _, exec_path = self._paths(mechanism, cfg, majority_first,
-                                      batch, pad_len)
+        """A deserialized AOT executable for the signature on this
+        backend, or ``None``."""
+        token = affinity_token(mechanism, cfg, majority_first, pad_len)
+        exec_path = self._exec_path(token, batch)
         if not os.path.exists(exec_path):
             with self._lock:
                 self.stats["disk_misses"] += 1
@@ -297,21 +310,19 @@ class CompileCache:
 
     # -- warming ---------------------------------------------------------
 
-    def warm(self, *, shard: int = 0, n_shards: int = 1,
-             mechanisms: Iterable[str] = ("hanoi_jax",)) -> WarmReport:
-        """Replay this shard's manifest slice through the adapter compile
-        path, so every hot signature is compiled (deserialized where the
+    def warm(self, *, mechanisms: Iterable[str] = ("hanoi_jax",)
+             ) -> WarmReport:
+        """Replay the manifest through the adapter compile path, so every
+        hot signature is compiled (deserialized where this backend's
         executable payload survives, re-traced otherwise) *before* the
         caller admits traffic."""
         from .adapters import _compiled_batch_exec, batch_cache_stats
 
         wanted = set(mechanisms)
-        report = WarmReport(shard=int(shard), n_shards=int(n_shards))
+        report = WarmReport()
         t0 = time.perf_counter()
         for entry in self.entries():
             if entry.mechanism not in wanted:
-                continue
-            if shard_of_token(entry.token, n_shards) != shard:
                 continue
             report.signatures += 1
             before = batch_cache_stats()
@@ -327,8 +338,8 @@ class CompileCache:
                 report.retraced += 1
             elif after["disk_hits"] > before["disk_hits"]:
                 report.loaded += 1
-            # a plain in-memory hit (duplicate manifest slice) counts as
-            # neither — the signature was already warm
+            # a plain in-memory hit counts as neither — the signature
+            # was already warm
         report.wall_s = time.perf_counter() - t0
         return report
 
@@ -336,7 +347,6 @@ class CompileCache:
         with self._lock:
             snap = dict(self.stats)
         snap["manifest_entries"] = len(self.entries())
-        snap["supports_serialization"] = supports_serialization()
         return snap
 
 

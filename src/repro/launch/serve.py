@@ -147,7 +147,8 @@ def _sim_main(args) -> None:
     from repro.engine import RotatingJsonlSink, SimRequest
     from repro.service import SimulationService
 
-    cfg = MachineConfig(n_threads=8, mem_size=64, max_steps=8192)
+    # the paper's machine: 32-lane warps (MachineConfig's default width)
+    cfg = MachineConfig(n_threads=32, max_steps=8192)
     suite = make_suite(cfg, datasets=1)
     bench = next((b for b in suite if b.name == args.bench), None)
     if bench is None:
@@ -178,6 +179,8 @@ def _sim_main(args) -> None:
                       f"status={sm.status.value} "
                       f"slots={sm.steps} cycles={sm.cycles} ipc={sm.ipc:.2f} "
                       f"util={sm.utilization:.3f}")
+                if not sm.ok:
+                    raise SystemExit(1)
                 return
             rng = np.random.default_rng(0)
             mix = (args.mix.split(",") if args.mix else [args.mechanism])
@@ -220,6 +223,8 @@ def _sim_main(args) -> None:
               f"disk={stats.cache_disk_hits} "
               f"warm={stats.warm_loaded}+{stats.warm_retraced}re "
               f"trace={stats.cache_trace_time_s:.2f}s")
+    if n_ok != len(results):
+        raise SystemExit(1)
 
 
 def _replay_main(args) -> None:
@@ -276,8 +281,8 @@ def main():
     ap.add_argument("--procs", type=int, default=0,
                     help="sim mode: size of the process-backed execution "
                          "tier; 0 (default) keeps the in-process thread "
-                         "pool, N>0 spawns N shard processes with "
-                         "signature-affine routing")
+                         "pool, N>0 spawns N shard processes (shard 0 "
+                         "runs all jax work; numpy groups spread)")
     ap.add_argument("--warm-start", default="",
                     help="sim mode: persistent compile-cache directory; "
                          "hot signatures recorded there are re-primed "
@@ -324,6 +329,8 @@ def main():
                     help="[replay] exit --watch after this long with no "
                          "new runs (0 = watch until --limit/interrupt)")
     args = ap.parse_args()
+    from repro.engine.compile_cache import install_jax_cache
+    install_jax_cache()
     if args.mode == "sim":
         _sim_main(args)
         return

@@ -16,11 +16,11 @@ Architecture (thread tier; see ``docs/service.md``)::
 
 With ``procs=N`` the dispatch queue + worker pool is replaced by the
 **process tier** (:mod:`repro.service.procpool`): flushed groups and SM
-cells route to N spawned shard processes — jax groups by signature
-affinity, numpy groups chunked across shards — and one collector thread
-resolves tickets from the reply queue.  ``warm_start=`` points both tiers
-at a persistent :mod:`repro.engine.compile_cache` directory that is
-replayed before traffic is admitted.
+cells route to N spawned shard processes — jax work to the one
+device-owning shard, numpy groups chunked across shards — and one
+collector thread resolves tickets from the reply queue.  ``warm_start=``
+points both tiers at a persistent :mod:`repro.engine.compile_cache`
+directory that is replayed before traffic is admitted.
 
 * **Admission**: ``submit`` coerces the request, derives its
   :class:`~repro.service.signature.ExecSignature`, hands it to the
@@ -69,7 +69,8 @@ from repro.engine.types import SimRequest, SimResult, SmResult
 
 from .coalescer import BatchCoalescer, FlushedGroup
 from .planner import group_is_native, run_group
-from .procpool import ArchiveSpec, ProcPool, ServiceStopped
+from .procpool import (DEVICE_SHARD, ArchiveSpec, ProcPool, ServiceStopped,
+                       sm_needs_device)
 from .signature import ExecSignature, shard_of, signature_of
 
 __all__ = ["ServiceStats", "ShardStats", "SimTicket", "SimulationService",
@@ -258,16 +259,18 @@ class SimulationService:
     procs:
         Shard *processes* (the process tier; ``0`` = classic thread tier).
         Flushed groups and SM cells route to spawned shard processes:
-        jax-backed groups by signature affinity (each shard keeps its own
-        hot jit/executable cache), numpy groups split into per-shard
-        chunks (no compiled state to keep local — spreading them is what
-        breaks the GIL's single-core ceiling).  See ``docs/service.md``.
+        jax-backed groups and SM cells to shard 0, the one process that
+        owns the device (a chip belongs to one process at a time), numpy
+        groups split into per-shard chunks (no compiled state to keep
+        local — spreading them is what breaks the GIL's single-core
+        ceiling).  This process itself never touches jax in this tier.
+        See ``docs/service.md``.
     warm_start:
         Directory of a persistent :class:`~repro.engine.compile_cache.
         CompileCache`.  Fresh compiles are recorded there; at start-up the
-        hot-signature manifest is replayed (each shard warms its affine
-        slice) *before* traffic is admitted, so restarts do not re-trace
-        on the serving path.
+        hot-signature manifest is replayed (by the device shard in the
+        process tier) *before* traffic is admitted, so restarts do not
+        re-trace on the serving path.
     archive:
         Optional :class:`~repro.engine.sinks.TraceSink` that receives every
         completed warp (whole runs, serialized under a service lock).  In
@@ -388,7 +391,7 @@ class SimulationService:
                 self._pool.wait_ready(timeout=300.0)
         elif self._warm_start:
             cache = install_compile_cache(self._warm_start)
-            self._warm_reports = [cache.warm(shard=0, n_shards=1).as_dict()]
+            self._warm_reports = [cache.warm().as_dict()]
         flusher = threading.Thread(target=self._flusher_loop, daemon=True,
                                    name="sim-service-flusher")
         flusher.start()
@@ -595,12 +598,15 @@ class SimulationService:
                 self._stats["inflight"] += job.warps
                 self._stats["repaired"] += n_repaired
             if self._pool is not None:
-                # cell-shape affinity: cells sharing (inner, policy, cfg,
-                # width) land on one shard and reuse its compiled SM state
-                token = (f"sm|{job.kwargs.get('inner') or self._default}"
-                         f"|{job.kwargs.get('policy')}|{job.cfg!r}"
-                         f"|w{job.warps}")
-                shard = self._pool.shard_for_token(token)
+                if sm_needs_device(job.kwargs, self._default):
+                    shard = DEVICE_SHARD
+                else:
+                    # cell-shape affinity for host cells: cells sharing
+                    # (inner, policy, cfg, width) land on one shard
+                    token = (f"sm|{job.kwargs.get('inner') or self._default}"
+                             f"|{job.kwargs.get('policy')}|{job.cfg!r}"
+                             f"|w{job.warps}")
+                    shard = self._pool.shard_for_token(token)
                 self._pool.submit_sm(
                     shard, programs=job.programs, cfg=job.cfg,
                     kwargs=job.kwargs, ctx=_PendingSm(job=job, shard=shard))
@@ -758,12 +764,12 @@ class SimulationService:
     def _route_group_to_pool(self, group: FlushedGroup[_WarpEntry]) -> None:
         """Process-tier routing of one flushed group.
 
-        Jax-backed groups go whole to their signature-affine shard — the
-        shard that owns (and stays hot on) that signature's jit/executable
-        cache state.  Numpy groups have no compiled state to keep local
-        and would serialize on one core if pinned, so they split into
-        per-shard chunks (round-robin base so successive groups cover
-        different shards even when the pool is wider than the group).
+        Jax-backed groups go whole to the device-owning shard, the only
+        process that may hold the chip.  Numpy groups have no compiled
+        state to keep local and would serialize on one core if pinned, so
+        they split into per-shard chunks (round-robin base so successive
+        groups cover different shards even when the pool is wider than
+        the group); a single numpy request goes to its signature's shard.
         """
         mech = get_mechanism(group.signature.mechanism)
         native = group_is_native(mech, group.signature)
@@ -785,7 +791,8 @@ class SimulationService:
                     ctx=_PendingGroup(entries=chunk, mechanism=mech.name,
                                       native=False, shard=shard))
         else:
-            shard = shard_of(group.signature, self._pool.n)
+            shard = (DEVICE_SHARD if mech.backend == "jax"
+                     else shard_of(group.signature, self._pool.n))
             self._pool.submit_group(
                 shard, mechanism=mech.name, native=native,
                 cause=group.cause, sig_key=group.signature.key,

@@ -9,18 +9,19 @@ worker processes (spawn, never fork — forking a process with a live JAX
 runtime is unsafe) and a routing discipline that preserves what made the
 single process fast:
 
-* **Signature-affine routing** — jax-backed groups hash their
-  :meth:`~repro.service.signature.ExecSignature.token` (mechanism +
-  canonical cfg + scheduling flavor + padding class) to one shard via a
-  stable crc32, so each process accumulates its *own* hot jit/executable
-  cache and pad-class locality instead of every shard re-compiling every
-  signature.  SM cells route the same way on a cell-shape token.
+* **One device-owning shard** — an accelerator belongs to one process at
+  a time, so shard :data:`DEVICE_SHARD` (0) runs every jax-backed group
+  and every SM cell whose engine or inner mechanism is jax-backed, and
+  keeps the only hot jit/executable cache.  The other shards refuse such
+  work rather than run it on the host CPU, and the parent process never
+  touches jax at all.
 * **Chunked spreading for cacheless work** — a numpy group has no compiled
   state to keep warm, and affine routing would pin a homogeneous numpy mix
   to ONE shard (exactly the single-core ceiling again).  The service
   splits such groups into per-shard chunks instead — that is where the
   ≥1.5x 1→2 process scaling gate in ``bench_service.py --smoke`` comes
-  from.
+  from.  Single numpy requests and numpy SM cells hash a stable
+  signature token to a shard.
 * **Picklable envelopes** — jobs (:class:`GroupJob` / :class:`SmJob`) and
   replies (:class:`Reply`) carry the frozen request/result dataclasses,
   which pickle via ``_PicklableMeta``; exceptions cross the boundary as
@@ -35,10 +36,9 @@ single process fast:
   :class:`~repro.engine.sinks.RotatingJsonlSink`, with disjoint SM-cell id
   ranges, so archival needs no cross-process lock and every family
   replays independently.
-* **Warm start** — a shard with a ``warm_start`` cache directory replays
-  *its* slice of the persistent compile-cache manifest (same affinity
-  hash) before signalling ready, so a restarted pool re-traces hot
-  signatures off the serving path.
+* **Warm start** — with a ``warm_start`` cache directory the device shard
+  replays the persistent compile-cache manifest before signalling ready,
+  so a restarted pool re-traces hot signatures off the serving path.
 
 Shutdown (:meth:`ProcPool.stop`) honors one shared deadline: sentinels, a
 bounded join, then ``terminate()`` for stragglers — which are reported by
@@ -55,13 +55,27 @@ import threading
 import time
 import traceback
 from dataclasses import dataclass, field
-from typing import Any, Callable
+from typing import Any, Callable, Mapping
 
 from repro.core.isa import MachineConfig
 from repro.engine.compile_cache import shard_of_token
+from repro.engine.registry import get_mechanism
 
 __all__ = ["ServiceStopped", "ArchiveSpec", "GroupJob", "SmJob", "Reply",
-           "RemoteError", "ProcPool"]
+           "RemoteError", "ProcPool", "DEVICE_SHARD", "sm_needs_device"]
+
+#: The shard that owns the accelerator and runs all jax work.
+DEVICE_SHARD = 0
+
+
+def sm_needs_device(kwargs: Mapping[str, Any], default_mechanism: str) -> bool:
+    """Whether an SM cell runs jax work: its engine (``sm_mechanism``) or
+    its inner mechanism (``inner``, else the service default) is
+    jax-backed.  Decided from names alone, so the parent routes without
+    touching jax."""
+    names = (kwargs.get("sm_mechanism") or "sm_interleave",
+             kwargs.get("inner") or default_mechanism)
+    return any(get_mechanism(n).backend == "jax" for n in names)
 
 
 class ServiceStopped(RuntimeError):
@@ -90,7 +104,6 @@ class _ShardSpec:
     """Everything a spawned shard needs to reconstruct its serving env."""
 
     shard: int
-    n_shards: int
     default_mechanism: str
     annotate: bool
     archive: ArchiveSpec | None
@@ -176,7 +189,7 @@ def _shard_main(spec: _ShardSpec, job_q, result_q) -> None:
 
     from repro.engine import sinks as sinks_mod
     from repro.engine.adapters import batch_cache_stats
-    from repro.engine.registry import get_mechanism
+    from repro.engine.compile_cache import install_jax_cache
     from repro.engine.simulator import Simulator
     from repro.engine.sinks import (RotatingJsonlSink, feed_result,
                                     next_sm_cell_id, run_meta, sm_run_meta,
@@ -187,6 +200,7 @@ def _shard_main(spec: _ShardSpec, job_q, result_q) -> None:
     # concurrently must never collide on (cell, warp) coordinates
     sinks_mod._sm_cell_ids = itertools.count(spec.shard * 1_000_000)
 
+    install_jax_cache()
     if spec.init is not None:
         spec.init(spec.shard)
 
@@ -197,10 +211,9 @@ def _shard_main(spec: _ShardSpec, job_q, result_q) -> None:
                                  max_bytes=spec.archive.max_bytes)
 
     warm = None
-    if spec.warm_start:
+    if spec.warm_start and spec.shard == DEVICE_SHARD:
         from repro.engine.compile_cache import install_compile_cache
-        cache = install_compile_cache(spec.warm_start)
-        warm = cache.warm(shard=spec.shard, n_shards=spec.n_shards).as_dict()
+        warm = install_compile_cache(spec.warm_start).warm().as_dict()
 
     result_q.put(_Ready(shard=spec.shard, pid=os.getpid(), warm=warm))
     sim = Simulator(spec.default_mechanism)
@@ -211,13 +224,24 @@ def _shard_main(spec: _ShardSpec, job_q, result_q) -> None:
                 "disk_hits": s["disk_hits"],
                 "trace_time_s": round(s["trace_time_s"], 6)}
 
+    def _refuse_device_work(device: bool) -> None:
+        if device and spec.shard != DEVICE_SHARD:
+            raise RuntimeError(
+                f"shard {spec.shard} was handed jax work; only shard "
+                f"{DEVICE_SHARD} owns the device")
+
     def _exec_group(job: GroupJob) -> list:
         mech = get_mechanism(job.mechanism)
+        device = mech.backend == "jax"
+        _refuse_device_work(device)
         results = run_group(mech, job.requests, native=job.native)
         if spec.annotate:
             svc_meta = {"batch_size": len(job.requests), "native": job.native,
                         "flush": job.cause, "signature": job.sig_key,
                         "shard": spec.shard}
+            if device:
+                import jax
+                svc_meta["platform"] = jax.devices()[0].platform
             results = [dataclasses.replace(r, meta={**r.meta,
                                                     "service": svc_meta})
                        for r in results]
@@ -230,6 +254,8 @@ def _shard_main(spec: _ShardSpec, job_q, result_q) -> None:
         return results
 
     def _exec_sm(job: SmJob):
+        _refuse_device_work(sm_needs_device(job.kwargs,
+                                            spec.default_mechanism))
         sm = sim.run_sm(job.programs, job.cfg, **job.kwargs)
         if sink is not None:
             cell = next_sm_cell_id()
@@ -310,7 +336,7 @@ class ProcPool:
         self._stop_event = threading.Event()
         self._shards: list[_ShardState] = []
         for k in range(self.n):
-            spec = _ShardSpec(shard=k, n_shards=self.n,
+            spec = _ShardSpec(shard=k,
                               default_mechanism=default_mechanism,
                               annotate=annotate, archive=archive,
                               warm_start=warm_start, init=shard_init)

@@ -79,17 +79,17 @@ class ExecSignature:
     @property
     def token(self) -> str:
         """The compiled-state locality token of this signature — the same
-        string the persistent compile cache stamps into its manifest, so
-        process-tier routing and warm-start sharding agree on which shard
-        owns which hot jit/executable cache state."""
+        string the persistent compile cache stamps into its manifest.  The
+        process tier hashes it to place single numpy requests; jax work
+        always goes to the device-owning shard."""
         return affinity_token(self.mechanism, self.cfg, self.majority_first,
                               self.pad_len)
 
 
 def shard_of(sig: ExecSignature, n_shards: int) -> int:
-    """Signature-affine shard assignment: a stable crc32 of the locality
-    token, mod the pool size.  Stable across processes and runs (unlike the
-    builtin ``hash``, which is salted per interpreter)."""
+    """Signature-affine shard assignment of host work: a stable crc32 of
+    the locality token, mod the pool size.  Stable across processes and
+    runs (unlike the builtin ``hash``, which is salted per interpreter)."""
     return shard_of_token(sig.token, n_shards)
 
 

@@ -7,8 +7,8 @@ import numpy as np
 import pytest
 
 from repro.core import MachineConfig
-from repro.core.hanoi import (run_hanoi_jax, run_warps_jax, state_deadlocked,
-                              state_trace)
+from repro.core.hanoi import (_put, run_hanoi_jax, run_warps_jax,
+                              state_deadlocked, state_trace)
 from repro.engine import Simulator
 from repro.core.programs import (fig5_program, fig6_program, make_suite,
                                  spinlock_program, warpsync_program)
@@ -137,3 +137,18 @@ def test_oracle_skip_on_jax_engine():
     st_ = run_hanoi_jax(prog, cfg, init_mem=mem, bsync_skip_pcs=skips)
     np.testing.assert_array_equal(np.asarray(st_.regs), ref.regs)
     assert tuple(state_trace(st_)) == ref.trace
+
+
+@pytest.mark.parametrize("i", [-9, -8, -1, 0, 3, 7, 8, 40])
+def test_put_matches_at_set(i):
+    """The lane step's one-hot update gives what ``.at[i].set`` gives:
+    negative indices count from the end, indices past the end change
+    nothing — on a vector and on the rows of a matrix."""
+    import jax
+    import jax.numpy as jnp
+    vec = jnp.arange(8, dtype=jnp.int32)
+    mat = jnp.arange(24, dtype=jnp.int32).reshape(8, 3)
+    row = jnp.array([-1, -2, -3], jnp.int32)
+    put = jax.jit(_put)
+    np.testing.assert_array_equal(put(vec, i, -5), vec.at[i].set(-5))
+    np.testing.assert_array_equal(put(mat, i, row), mat.at[i].set(row))

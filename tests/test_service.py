@@ -536,6 +536,30 @@ def test_serve_simulations_thin_client():
         _same_outcome(res, SIM.run(req))
 
 
+@pytest.mark.parametrize("bench,mechanism,code", [
+    ("DIAMOND", "hanoi", None),
+    ("SLOCK", "simt_stack", 1),          # pre-Volta stack deadlocks (Fig 3)
+])
+def test_serve_sim_cli_exit_code(bench, mechanism, code, tmp_path,
+                                 monkeypatch, capsys):
+    """``serve --mode sim`` serves the 32-lane machine and exits 1 when
+    any request failed."""
+    import sys
+    from repro.launch import serve as serve_mod
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    monkeypatch.setattr(sys, "argv", [
+        "serve", "--mode", "sim", "--bench", bench, "--mechanism", mechanism,
+        "--batch", "2"])
+    if code is None:
+        serve_mod.main()
+    else:
+        with pytest.raises(SystemExit) as ei:
+            serve_mod.main()
+        assert ei.value.code == code
+    out = capsys.readouterr().out
+    assert f"2 x {bench} via {mechanism}" in out
+
+
 # ---------------------------------------------------------------------------
 # regressions (ISSUE 4 satellites): percentile indexing + sink accounting
 # ---------------------------------------------------------------------------

@@ -13,8 +13,10 @@ from __future__ import annotations
 
 import glob
 import os
+import queue
 import time
 
+import jax
 import numpy as np
 import pytest
 
@@ -25,11 +27,12 @@ from repro.core.programs import diamond_program, make_suite
 from repro.engine import (RotatingJsonlSink, Simulator, adapters,
                           iter_mechanisms, register_mechanism,
                           unregister_mechanism)
+from repro.engine import compile_cache
 from repro.engine.compile_cache import (CompileCache, affinity_token,
-                                        shard_of_token,
-                                        supports_serialization)
+                                        install_jax_cache, shard_of_token)
 from repro.engine.simulator import as_request
 from repro.service import ServiceStopped, SimulationService
+from repro.service.procpool import ProcPool
 
 CFG = MachineConfig(n_threads=8, mem_size=64, max_steps=4096)
 SUITE = make_suite(CFG, datasets=1)
@@ -127,12 +130,59 @@ def test_numpy_groups_spread_across_shards():
 
 
 def test_jax_groups_route_affine_to_one_shard():
-    """A signature-homogeneous jax group keeps its executable-cache
-    locality: the whole group lands on its affinity shard."""
-    with SimulationService(default_mechanism="hanoi_jax", procs=2) as svc:
+    """Jax groups run whole on shard 0, the one process that owns the
+    device, while numpy groups still spread over every shard."""
+    with SimulationService(default_mechanism="hanoi_jax", procs=2,
+                           max_batch=64) as svc:
         res = svc.run(_reqs(6), timeout=300)
-        shards = {r.meta["service"]["shard"] for r in res}
-    assert len(shards) == 1
+        host = svc.run(_reqs(6), mechanism="hanoi", timeout=120)
+    assert {r.meta["service"]["shard"] for r in res} == {0}
+    assert {r.meta["service"]["platform"] for r in res} == {
+        jax.devices()[0].platform}
+    assert {r.meta["service"]["shard"] for r in host} == {0, 1}
+
+
+def test_device_sm_cells_run_on_shard_zero():
+    """SM cells whose inner mechanism (or engine) is jax-backed route to
+    the device shard; bit-equal to the single-process façade."""
+    progs = [b.program for b in SUITE[:2]]
+    cells = [dict(programs=progs, cfg=CFG, n_warps=2, inner="hanoi_jax",
+                  policy=p) for p in ("round_robin", "greedy_then_oldest")]
+    with SimulationService(default_mechanism="hanoi", procs=2) as svc:
+        got = svc.run_sm_grid(cells, timeout=300)
+        st = svc.stats()
+    assert [s.jobs for s in st.shards] == [2, 0]
+    for cell, sm in zip(cells, got):
+        want = SIM.run_sm(progs, CFG, n_warps=2, inner="hanoi",
+                          policy=cell["policy"])
+        assert sm.sm_trace == want.sm_trace and sm.cycles == want.cycles
+
+
+def test_non_device_shard_refuses_device_work():
+    """Handed jax work directly, shard 1 raises instead of running it on
+    the host CPU; its numpy work still runs."""
+    replies: queue.Queue = queue.Queue()
+    pool = ProcPool(2, default_mechanism="hanoi", annotate=False,
+                    on_reply=lambda ctx, payload, error:
+                    replies.put((ctx, error)))
+    try:
+        assert pool.wait_ready(timeout=120)
+        req = _reqs(1)[0]
+        for ctx, mech in (("jax", "hanoi_jax"), ("host", "hanoi")):
+            pool.submit_group(1, mechanism=mech, native=False, cause="manual",
+                              sig_key=ctx, requests=[req], ctx=ctx)
+        pool.submit_sm(1, programs=[req.program] * 2, cfg=CFG,
+                       kwargs={"n_warps": 2, "inner": "hanoi_jax"}, ctx="sm")
+        pool.submit_sm(1, programs=[req.program] * 2, cfg=CFG,
+                       kwargs={"n_warps": 2, "inner": "hanoi",
+                               "sm_mechanism": "sm_jax"}, ctx="sm_jax")
+        errors = dict(replies.get(timeout=120) for _ in range(4))
+    finally:
+        pool.stop(deadline=time.monotonic() + 30)
+    assert errors["host"] is None
+    for ctx in ("jax", "sm", "sm_jax"):
+        assert isinstance(errors[ctx], RuntimeError)
+        assert "only shard 0 owns the device" in str(errors[ctx])
 
 
 def test_sm_grid_bit_equal_through_two_procs():
@@ -308,11 +358,10 @@ def test_warm_start_restarted_service_retraces_zero(tmp_path):
     # the warm-start contract: hot signatures never re-trace at serve time
     assert st2.cache_misses == st2.warm_retraced
     assert st2.cache_hits >= 2
-    if supports_serialization():
-        # this jaxlib deserializes AOT executables: zero re-trace anywhere
-        assert st2.warm_retraced == 0
-        assert st2.warm_loaded >= 2
-        assert st2.cache_misses == 0
+    # AOT executables deserialize: zero re-trace anywhere
+    assert st2.warm_retraced == 0
+    assert st2.warm_loaded >= 2
+    assert st2.cache_misses == 0
 
 
 def test_thread_tier_warm_start(tmp_path):
@@ -333,6 +382,48 @@ def test_thread_tier_warm_start(tmp_path):
     finally:
         uninstall_compile_cache()
         adapters.reset_batch_caches()
+
+
+def test_executable_from_another_backend_is_a_miss(tmp_path, monkeypatch):
+    """An entry serialized on another platform is a clean disk miss on
+    this one — never handed to the loader to fail as a load error."""
+    cache = CompileCache(str(tmp_path))
+    compiled, _ = adapters._compiled_batch_exec(CFG, True, 1, 32)
+    monkeypatch.setattr(compile_cache, "_backend_key",
+                        lambda: "tpu|TPU v5 lite|jax0.9.0")
+    assert cache.store_executable("hanoi_jax", CFG, True, 1, 32, compiled)
+    monkeypatch.undo()
+    assert cache.has("hanoi_jax", CFG, True, 1, 32)     # still hot
+    assert cache.load_executable("hanoi_jax", CFG, True, 1, 32) is None
+    assert cache.stats["disk_misses"] == 1
+    assert cache.stats["load_errors"] == 0
+    assert cache.store_executable("hanoi_jax", CFG, True, 1, 32, compiled)
+    assert cache.load_executable("hanoi_jax", CFG, True, 1, 32) is not None
+    assert cache.stats["disk_hits"] == 1
+
+
+@pytest.mark.parametrize("env", [True, False])
+def test_install_jax_cache_places_the_cache(env, tmp_path, monkeypatch):
+    """``JAX_COMPILATION_CACHE_DIR`` wins and nothing else is set;
+    otherwise the cache is the fixed, git-ignored ``<checkout>/.jax_cache``."""
+    checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    prev = jax.config.jax_compilation_cache_dir
+    if env:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        got = install_jax_cache()
+        if env:
+            assert got == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == prev
+        else:
+            assert got == os.path.join(checkout, ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == got
+            with open(os.path.join(checkout, ".gitignore")) as f:
+                assert ".jax_cache/" in f.read().split()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
 
 
 # ---------------------------------------------------------------------------
