@@ -31,6 +31,7 @@ from typing import Sequence
 
 import numpy as np
 
+from repro import obs
 from repro.core.interp import RunResult, run_hanoi, run_simt_stack, \
     simd_utilization
 from repro.core.dualpath import run_dual_path
@@ -219,6 +220,7 @@ def _jitted_batch_runner(cfg, majority_first: bool):
     import jax
     from repro.core.hanoi import _run, init_state
 
+    # the name is the XLA module's in profiler traces (``jit_one``)
     def one(prog, skip, reg, mem, lane):
         st = init_state(prog.shape[0], cfg, init_regs=reg, init_mem=mem,
                         lane_ids=lane)
@@ -345,8 +347,9 @@ def _compiled_batch_exec(cfg, majority_first: bool, batch: int, pad_len: int):
 
     cache = installed_cache()
     if cache is not None:
-        compiled = cache.load_executable("hanoi_jax", cfg, majority_first,
-                                         batch, pad_len)
+        with obs.span("sim.compile"):
+            compiled = cache.load_executable("hanoi_jax", cfg,
+                                             majority_first, batch, pad_len)
         if compiled is not None:
             with _BATCH_CACHE_LOCK:
                 _BATCH_STATS["disk_hits"] += 1
@@ -365,15 +368,14 @@ def _compiled_batch_exec(cfg, majority_first: bool, batch: int, pad_len: int):
     # cache serializes is compiled fresh
     fresh = (jax_config.enable_compilation_cache(False) if cache is not None
              else contextlib.nullcontext())
-    t0 = time.perf_counter()
-    with fresh:
+    with obs.span("sim.compile") as timed, fresh:
         compiled = _jitted_batch_runner(cfg, majority_first).lower(
             sds((batch, pad_len, 8), jnp.int32),
             sds((batch, pad_len), jnp.bool_),
             sds((batch, W, cfg.n_regs), jnp.int32),
             sds((batch, cfg.mem_size), jnp.int32),
             sds((batch, W), jnp.int32)).compile()
-    compile_s = time.perf_counter() - t0
+    compile_s = timed.seconds
     with _BATCH_CACHE_LOCK:
         _BATCH_STATS["misses"] += 1
         _BATCH_STATS["trace_time_s"] += compile_s
@@ -402,22 +404,43 @@ def _run_hanoi_jax_batch(reqs: Sequence[SimRequest]) -> list[SimResult]:
 
     cfg = reqs[0].resolved_cfg()
     majority_first = reqs[0].majority_first
-    L = padded_len(max(int(np.asarray(r.program).shape[0]) for r in reqs))
-    progs, skips, regs, mems, lanes = _batch_arrays(reqs, cfg, L)
+    with obs.span("sim.pack"):
+        L = padded_len(max(int(np.asarray(r.program).shape[0])
+                           for r in reqs))
+        progs, skips, regs, mems, lanes = _batch_arrays(reqs, cfg, L)
 
     compiled, compile_s = _compiled_batch_exec(cfg, majority_first,
                                                len(reqs), L)
-    t0 = time.perf_counter()
-    states = compiled(jnp.asarray(progs), jnp.asarray(skips),
-                      jnp.asarray(regs), jnp.asarray(mems),
-                      jnp.asarray(lanes))
-    jax.block_until_ready(states.regs)
-    wall = (time.perf_counter() - t0) / max(1, len(reqs))
+    with obs.span("sim.lane_step") as lane:
+        states = compiled(jnp.asarray(progs), jnp.asarray(skips),
+                          jnp.asarray(regs), jnp.asarray(mems),
+                          jnp.asarray(lanes))
+        jax.block_until_ready(states.regs)
+    wall = lane.seconds / max(1, len(reqs))
     meta = {"compile_time_s": compile_s} if compile_s is not None else None
-    per_warp = [jax.tree_util.tree_map(lambda x, i=i: x[i], states)
-                for i in range(len(reqs))]
-    return [_jax_result(r, st, wall, meta=meta)
-            for r, st in zip(reqs, per_warp)]
+    with obs.span("sim.assemble"):
+        per_warp = [jax.tree_util.tree_map(lambda x, i=i: x[i], states)
+                    for i in range(len(reqs))]
+        results = [_jax_result(r, st, wall, meta=meta)
+                   for r, st in zip(reqs, per_warp)]
+    if obs.enabled():
+        _count_lane_step(cfg, [r.steps for r in results],
+                        [r.fuel_left for r in results])
+    return results
+
+
+def _count_lane_step(cfg, steps, fuel_left) -> None:
+    """Feed the lane-step counters for one executed batch (every row,
+    padding included; ``steps`` only for the rows that are not padding).
+
+    ``lane_step.row_iterations`` is rows x the batch's ``while_loop`` trip
+    count.  Each iteration spends one unit of fuel, executing an
+    instruction or not (a reconvergence, a halt), so the trip count is the
+    most fuel any row spent, which can exceed its ``steps``."""
+    spent = max(cfg.max_steps - int(f) for f in fuel_left)
+    obs.count("lane_step.rows", len(fuel_left))
+    obs.count("lane_step.row_iterations", len(fuel_left) * spent)
+    obs.count("lane_step.useful_steps", sum(int(s) for s in steps))
 
 
 @register_mechanism(

@@ -34,11 +34,12 @@ warp phase *is* the jitted Hanoi lane step, bit-identical to both).
 from __future__ import annotations
 
 import dataclasses
-import time
+import itertools
 from typing import Sequence
 
 import numpy as np
 
+from repro import obs
 from repro.core.isa import ATOMIC_OPS, F_OP, MEMORY_OPS, Op
 from repro.core.timing import TimingConfig
 from repro.timing import CycleConfig
@@ -46,7 +47,7 @@ from repro.timing.policies import POLICY_NAMES, resolve_policy_name
 from repro.timing.sm_model import _CONTROL_LAT_OPS
 
 from ..adapters import _batch_arrays, _compiled_batch_exec, _jax_result, \
-    padded_len
+    _count_lane_step, padded_len
 from ..registry import get_mechanism, register_mechanism
 from ..types import SimRequest, SimResult, SmResult, worst_status
 from .sm import DEFAULT_POLICY, _sm_options
@@ -161,6 +162,7 @@ def _cell_scheduler(n_warps: int, out_cap: int, policy_id: int,
             return (w_ids - cursor) % n_warps
         return w_ids                                   # oldest_first
 
+    # the name is the XLA module's in profiler traces (``jit_schedule``)
     def schedule(warp_map, trace_n, ops, trace_pc_u, trace_mask_u):
         # warp_map[w] -> row in the hash-consed trace buffers (shared,
         # un-vmapped operands): replicated warps read one trace copy.
@@ -265,16 +267,19 @@ def _compiled_grid_scheduler(n_cells: int, n_warps: int, n_uniq: int,
                                           lat_tab, mem_tab),
                           in_axes=(0, 0, 0, None, None)))
     sds = jax.ShapeDtypeStruct
-    t0 = time.perf_counter()
-    compiled = fn.lower(
-        sds((n_cells, n_warps), jnp.int32),           # warp_map
-        sds((n_cells, n_warps), jnp.int32),           # trace_n
-        sds((n_cells, n_warps, prog_len), jnp.int32),  # opcode columns
-        sds((n_uniq, trace_cap), jnp.int32),          # hash-consed traces
-        sds((n_uniq, trace_cap), jnp.uint32)).compile()
-    compile_s = time.perf_counter() - t0
+    with obs.span("sim.compile") as timed:
+        compiled = fn.lower(
+            sds((n_cells, n_warps), jnp.int32),           # warp_map
+            sds((n_cells, n_warps), jnp.int32),           # trace_n
+            sds((n_cells, n_warps, prog_len), jnp.int32),  # opcode columns
+            sds((n_uniq, trace_cap), jnp.int32),          # hash-consed traces
+            sds((n_uniq, trace_cap), jnp.uint32)).compile()
     _SCHED_CACHE[key] = compiled
-    return compiled, compile_s
+    return compiled, timed.seconds
+
+
+#: sequence number of each ``run_cells`` call, the ``call`` of its span
+_GRID_CALLS = itertools.count()
 
 
 def run_cells(cells: Sequence[Sequence[SimRequest]], *,
@@ -290,118 +295,144 @@ def run_cells(cells: Sequence[Sequence[SimRequest]], *,
     differ in program, memory image, registers and lane ids (heterogeneous
     cells).  All cells must have the same warp count (one compiled
     scheduler steps the whole grid).
+
+    Each cell's ``wall_time_s`` is its even share of the ``sim.lane_step``
+    and ``sim.schedule`` spans (:mod:`repro.obs`).
     """
-    policy_name = resolve_policy_name(policy)
-    ccfg = _supported_cycle_cfg(timing_cfg)
-    if inner_label not in _SUPPORTED_INNER:
-        raise ValueError(
-            f"sm_jax executes warps on the jitted hanoi lane step; inner "
-            f"must be one of {_SUPPORTED_INNER}, got {inner_label!r} — use "
-            f"sm_interleave for other inner mechanisms")
-    if not cells or any(not cell for cell in cells):
-        raise ValueError("run_cells needs at least one warp per cell")
-    n_warps = len(cells[0])
-    if any(len(cell) != n_warps for cell in cells):
-        raise ValueError("all cells in one sm_jax grid must share a warp "
-                         "count")
-    flat = [q for cell in cells for q in cell]
-    cfg = flat[0].resolved_cfg()
-    mf, record = flat[0].majority_first, flat[0].record_trace
-    for q in flat:
-        if q.resolved_cfg() != cfg or q.majority_first != mf \
-                or q.record_trace != record:
-            raise ValueError("sm_jax warps must share cfg, majority_first "
-                             "and record_trace across the grid")
-        if q.active0 is not None:
-            raise ValueError("sm_jax assumes a full entry mask "
-                             "(active0=None)")
+    with obs.span("sim.run_cells", call=next(_GRID_CALLS)):
+        policy_name = resolve_policy_name(policy)
+        ccfg = _supported_cycle_cfg(timing_cfg)
+        if inner_label not in _SUPPORTED_INNER:
+            raise ValueError(
+                f"sm_jax executes warps on the jitted hanoi lane step; "
+                f"inner must be one of {_SUPPORTED_INNER}, got "
+                f"{inner_label!r} — use sm_interleave for other inner "
+                f"mechanisms")
+        if not cells or any(not cell for cell in cells):
+            raise ValueError("run_cells needs at least one warp per cell")
+        n_warps = len(cells[0])
+        if any(len(cell) != n_warps for cell in cells):
+            raise ValueError("all cells in one sm_jax grid must share a "
+                             "warp count")
+        flat = [q for cell in cells for q in cell]
+        cfg = flat[0].resolved_cfg()
+        mf, record = flat[0].majority_first, flat[0].record_trace
+        for q in flat:
+            if q.resolved_cfg() != cfg or q.majority_first != mf \
+                    or q.record_trace != record:
+                raise ValueError("sm_jax warps must share cfg, "
+                                 "majority_first and record_trace across "
+                                 "the grid")
+            if q.active0 is not None:
+                raise ValueError("sm_jax assumes a full entry mask "
+                                 "(active0=None)")
 
-    import jax
-    import jax.numpy as jnp
+        import jax
+        import jax.numpy as jnp
 
-    # phase 1: hash-cons the warp rows — identical (program, skips, regs,
-    # mem, lanes) rows execute ONCE through the shared hanoi batch
-    # executable (same compile cache as the hanoi_jax service path).  The
-    # replicated-warp path collapses N identical warps per cell to one
-    # row, so a whole grid costs #unique-programs lane executions.
-    L = padded_len(max(int(np.asarray(q.program).shape[0]) for q in flat))
-    progs, skips, regs, mems, lanes = _batch_arrays(flat, cfg, L)
-    first, inv = _dedupe_rows(progs, skips, regs, mems, lanes)
-    n_uniq = _batch_class(len(first))                 # batch-size class
-    sel = np.concatenate([first, np.full(n_uniq - len(first), first[0],
-                                         dtype=np.int64)])
-    compiled, compile_s = _compiled_batch_exec(cfg, mf, n_uniq, L)
-    t0 = time.perf_counter()
-    states = compiled(jnp.asarray(progs[sel]), jnp.asarray(skips[sel]),
-                      jnp.asarray(regs[sel]), jnp.asarray(mems[sel]),
-                      jnp.asarray(lanes[sel]))
-    jax.block_until_ready(states.regs)
-    exec_s = time.perf_counter() - t0
-    dev_pc, dev_mask = states.trace_pc, states.trace_mask  # stay on device
-    states = jax.tree_util.tree_map(np.asarray, states)
+        # phase 1: hash-cons the warp rows — identical (program, skips,
+        # regs, mem, lanes) rows execute ONCE through the shared hanoi batch
+        # executable (same compile cache as the hanoi_jax service path).
+        # The replicated-warp path collapses N identical warps per cell to
+        # one row, so a whole grid costs #unique-programs lane executions.
+        with obs.span("sim.pack"):
+            L = padded_len(max(int(np.asarray(q.program).shape[0])
+                               for q in flat))
+            progs, skips, regs, mems, lanes = _batch_arrays(flat, cfg, L)
+            first, inv = _dedupe_rows(progs, skips, regs, mems, lanes)
+            n_uniq = _batch_class(len(first))         # batch-size class
+            sel = np.concatenate([first, np.full(n_uniq - len(first),
+                                                 first[0], dtype=np.int64)])
+            rows = [a[sel] for a in (progs, skips, regs, mems, lanes)]
+        compiled, compile_s = _compiled_batch_exec(cfg, mf, n_uniq, L)
+        with obs.span("sim.lane_step") as lane:
+            states = compiled(*(jnp.asarray(a) for a in rows))
+            jax.block_until_ready(states.regs)
+        exec_s = lane.seconds
+        dev_pc, dev_mask = states.trace_pc, states.trace_mask  # on device
+        with obs.span("sim.assemble"):
+            states = jax.tree_util.tree_map(np.asarray, states)
+        if obs.enabled():
+            # rows past len(first) repeat row 0: padding, not useful work
+            _count_lane_step(cfg, states.steps[:len(first)], states.fuel)
 
-    C, N, T = len(cells), n_warps, cfg.max_steps
-    warp_map = inv.reshape(C, N).astype(np.int32)
-    trace_n = states.trace_n[inv].reshape(C, N).astype(np.int32)
-    total_compile = compile_s or 0.0
-    scheduled = bool(record) and int(trace_n.max(initial=0)) > 0
-    if scheduled:
-        # phase 2: the whole grid through one compiled vmapped scheduler;
-        # the hash-consed trace buffers are passed un-vmapped, so warps
-        # gather their (pc, mask) stream from one device-resident copy
-        ops = progs[:, :, F_OP].reshape(C, N, L)
-        out_cap = _out_capacity(int(trace_n.sum(axis=1).max()))
-        lat_key = (ccfg.alu_latency, ccfg.control_latency,
-                   ccfg.memory_latency, ccfg.atomic_latency)
-        sched, sched_compile_s = _compiled_grid_scheduler(
-            C, N, n_uniq, T, L, out_cap, _POLICY_IDS[policy_name], lat_key)
-        total_compile += sched_compile_s or 0.0
-        t0 = time.perf_counter()
-        out = sched(jnp.asarray(warp_map), jnp.asarray(trace_n),
-                    jnp.asarray(ops), dev_pc, dev_mask)
-        out = [np.asarray(x) for x in jax.block_until_ready(out)]
-        exec_s += time.perf_counter() - t0
-        ow, opc, om, out_n, cycles, busy, istall, sstall, mstall, tinstr = out
-
-    warp_wall = exec_s / max(1, len(flat))
-    cell_wall = exec_s / max(1, C)
-    sm_meta = {"compile_time_s": total_compile} if total_compile else {}
-    width = cfg.n_threads
-    # one SimResult per unique row, shared by every warp that hash-consed
-    # onto it (SimResult is frozen; SmResult.requests keeps per-warp names)
-    uniq_results = [
-        _jax_result(flat[int(first[u])],
-                    jax.tree_util.tree_map(lambda x, u=u: x[u], states),
-                    warp_wall)
-        for u in range(len(first))]
-    sms: list[SmResult] = []
-    for c, cell in enumerate(cells):
-        warps = tuple(uniq_results[inv[i]]
-                      for i in range(c * N, (c + 1) * N))
+        C, N, T = len(cells), n_warps, cfg.max_steps
+        total_compile = compile_s or 0.0
+        with obs.span("sim.pack"):
+            warp_map = inv.reshape(C, N).astype(np.int32)
+            trace_n = states.trace_n[inv].reshape(C, N).astype(np.int32)
+            scheduled = bool(record) and int(trace_n.max(initial=0)) > 0
+            if scheduled:
+                ops = progs[:, :, F_OP].reshape(C, N, L)
+                out_cap = _out_capacity(int(trace_n.sum(axis=1).max()))
         if scheduled:
-            n_c = int(out_n[c])
-            sm_trace = tuple(zip(ow[c, :n_c].tolist(),
-                                 opc[c, :n_c].tolist(),
-                                 om[c, :n_c].tolist()))
-            kw = dict(steps=n_c, cycles=int(cycles[c]),
-                      thread_instructions=int(tinstr[c]),
-                      utilization=int(tinstr[c]) / max(1, n_c * width),
-                      busy_cycles=int(busy[c]),
-                      issue_stall_cycles=int(istall[c]),
-                      scoreboard_stall_cycles=int(sstall[c]),
-                      memory_stall_cycles=int(mstall[c]))
-        else:
-            sm_trace = ()
-            kw = dict(steps=0, cycles=0, thread_instructions=0,
-                      utilization=0.0, busy_cycles=0, issue_stall_cycles=0,
-                      scoreboard_stall_cycles=0, memory_stall_cycles=0)
-        sms.append(SmResult(
-            mechanism="sm_jax", inner=inner_label, policy=policy_name,
-            warps=warps, sm_trace=sm_trace,
-            status=worst_status([r.status for r in warps]),
-            requests=tuple(cell), wall_time_s=cell_wall, meta=sm_meta,
-            **kw))
-    return sms
+            # phase 2: the whole grid through one compiled vmapped
+            # scheduler; the hash-consed trace buffers are passed
+            # un-vmapped, so warps gather their (pc, mask) stream from one
+            # device-resident copy
+            lat_key = (ccfg.alu_latency, ccfg.control_latency,
+                       ccfg.memory_latency, ccfg.atomic_latency)
+            sched, sched_compile_s = _compiled_grid_scheduler(
+                C, N, n_uniq, T, L, out_cap, _POLICY_IDS[policy_name],
+                lat_key)
+            total_compile += sched_compile_s or 0.0
+            with obs.span("sim.schedule") as sched_span:
+                out = sched(jnp.asarray(warp_map), jnp.asarray(trace_n),
+                            jnp.asarray(ops), dev_pc, dev_mask)
+                out = [np.asarray(x) for x in jax.block_until_ready(out)]
+            exec_s += sched_span.seconds
+            ow, opc, om, out_n, cycles, busy, istall, sstall, mstall, \
+                tinstr = out
+            if obs.enabled():
+                obs.count("schedule.slots_scanned", C * out_cap)
+                obs.count("schedule.slots_issued", int(out_n.sum()))
+
+        with obs.span("sim.assemble"):
+            warp_wall = exec_s / max(1, len(flat))
+            cell_wall = exec_s / max(1, C)
+            sm_meta = {"compile_time_s": total_compile} if total_compile \
+                else {}
+            width = cfg.n_threads
+            # one SimResult per unique row, shared by every warp that
+            # hash-consed onto it (SimResult is frozen; SmResult.requests
+            # keeps per-warp names)
+            uniq_results = [
+                _jax_result(flat[int(first[u])],
+                            jax.tree_util.tree_map(lambda x, u=u: x[u],
+                                                   states),
+                            warp_wall)
+                for u in range(len(first))]
+            sms: list[SmResult] = []
+            for c, cell in enumerate(cells):
+                warps = tuple(uniq_results[inv[i]]
+                              for i in range(c * N, (c + 1) * N))
+                if scheduled:
+                    n_c = int(out_n[c])
+                    sm_trace = tuple(zip(ow[c, :n_c].tolist(),
+                                         opc[c, :n_c].tolist(),
+                                         om[c, :n_c].tolist()))
+                    kw = dict(steps=n_c, cycles=int(cycles[c]),
+                              thread_instructions=int(tinstr[c]),
+                              utilization=int(tinstr[c])
+                              / max(1, n_c * width),
+                              busy_cycles=int(busy[c]),
+                              issue_stall_cycles=int(istall[c]),
+                              scoreboard_stall_cycles=int(sstall[c]),
+                              memory_stall_cycles=int(mstall[c]))
+                else:
+                    sm_trace = ()
+                    kw = dict(steps=0, cycles=0, thread_instructions=0,
+                              utilization=0.0, busy_cycles=0,
+                              issue_stall_cycles=0,
+                              scoreboard_stall_cycles=0,
+                              memory_stall_cycles=0)
+                sms.append(SmResult(
+                    mechanism="sm_jax", inner=inner_label,
+                    policy=policy_name, warps=warps, sm_trace=sm_trace,
+                    status=worst_status([r.status for r in warps]),
+                    requests=tuple(cell), wall_time_s=cell_wall,
+                    meta=sm_meta, **kw))
+        return sms
 
 
 def _sm_jax_options(req: SimRequest) -> tuple[int, str, str]:
@@ -432,16 +463,18 @@ def _run_sm_jax_batch(reqs: Sequence[SimRequest]) -> list[SimResult]:
                       for w in range(n_warps)])
     sms = run_cells(cells, policy=policy, inner_label=inner_name)
     out = []
-    for sm in sms:
-        w0 = sm.warps[0]
-        out.append(SimResult(
-            mechanism="sm_jax", status=sm.status,
-            regs=w0.regs, preds=w0.preds, mem=w0.mem, finished=w0.finished,
-            steps=sm.steps, fuel_left=min(r.fuel_left for r in sm.warps),
-            trace=tuple((pc, mask) for _, pc, mask in sm.sm_trace),
-            utilization=sm.utilization,
-            error=next((r.error for r in sm.warps if r.error), None),
-            wall_time_s=sm.wall_time_s, meta={"sm": sm}))
+    with obs.span("sim.assemble"):
+        for sm in sms:
+            w0 = sm.warps[0]
+            out.append(SimResult(
+                mechanism="sm_jax", status=sm.status,
+                regs=w0.regs, preds=w0.preds, mem=w0.mem,
+                finished=w0.finished, steps=sm.steps,
+                fuel_left=min(r.fuel_left for r in sm.warps),
+                trace=tuple((pc, mask) for _, pc, mask in sm.sm_trace),
+                utilization=sm.utilization,
+                error=next((r.error for r in sm.warps if r.error), None),
+                wall_time_s=sm.wall_time_s, meta={"sm": sm}))
     return out
 
 

@@ -17,6 +17,7 @@ from typing import Any, Iterable, Sequence
 
 import numpy as np
 
+from repro import obs
 from repro.core.isa import MachineConfig
 from repro.core.timing import TimingConfig, ipc_delta, simulate
 from repro.core.trace import discrepancy
@@ -27,6 +28,9 @@ from .sinks import (TraceSink, feed_result, next_sm_cell_id, run_meta,
 from .types import SimRequest, SimResult, SmResult
 
 ProgramLike = Any    # np.ndarray | Benchmark | SimRequest
+
+#: sequence number of each ``run_batch`` call, the ``call`` of its span
+_BATCH_CALLS = itertools.count()
 
 
 def as_request(program: ProgramLike, cfg: MachineConfig | None = None,
@@ -229,19 +233,20 @@ class Simulator:
         sequentially unless the Simulator was built with ``max_workers``
         (see class docstring).
         """
-        mech = get_mechanism(mechanism or self._default)
-        reqs = [as_request(p, cfg, **request_kw) for p in programs]
-        if not reqs:
-            return []
-        if synthesize:
-            reqs = self._synthesize(reqs)
-        self._check(reqs, verify)
-        from repro.service.planner import execute_plan   # lazy: no cycle at
-        results = execute_plan(mech, reqs,               # package import time
-                               max_workers=self._max_workers)
-        for req, res in zip(reqs, results):
-            self._feed_sink(sink or self._sink, mech, req, res)
-        return results
+        with obs.span("sim.run_batch", call=next(_BATCH_CALLS)):
+            mech = get_mechanism(mechanism or self._default)
+            reqs = [as_request(p, cfg, **request_kw) for p in programs]
+            if not reqs:
+                return []
+            if synthesize:
+                reqs = self._synthesize(reqs)
+            self._check(reqs, verify)
+            from repro.service.planner import execute_plan  # lazy: no cycle
+            results = execute_plan(mech, reqs,    # at package import time
+                                   max_workers=self._max_workers)
+            for req, res in zip(reqs, results):
+                self._feed_sink(sink or self._sink, mech, req, res)
+            return results
 
     # -- per-SM multi-warp execution ----------------------------------------
 

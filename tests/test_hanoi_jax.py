@@ -152,3 +152,56 @@ def test_put_matches_at_set(i):
     put = jax.jit(_put)
     np.testing.assert_array_equal(put(vec, i, -5), vec.at[i].set(-5))
     np.testing.assert_array_equal(put(mat, i, row), mat.at[i].set(row))
+
+
+# ---------------------------------------------------------------------------
+# the batch path's spans and lane-step counters (repro.obs)
+# ---------------------------------------------------------------------------
+
+def test_batch_feeds_the_lane_step_counters_and_spans():
+    """A hanoi_jax batch counts every row, its loop's trip count (the most
+    fuel a row spent: reconvergence and halt iterations spend fuel without
+    a step) and the rows' steps; its wall time is the lane-step span's."""
+    from repro import obs
+    from repro.engine import SimRequest
+    from repro.engine.adapters import padded_len
+    cfg = MachineConfig(n_threads=4, mem_size=48, max_steps=1536)
+    suite = [b for b in make_suite(cfg, datasets=2)
+             if padded_len(len(b.program)) == 32][:5]
+    assert len(suite) == 5
+    reqs = [SimRequest(program=b.program, cfg=cfg, init_mem=b.init_mem,
+                       name=b.name) for b in suite]
+    obs.reset()
+    obs.enable()
+    try:
+        results = Simulator("hanoi_jax").run_batch(reqs)
+    finally:
+        obs.disable()
+    snap = obs.snapshot()
+    obs.reset()
+    counters, spans = snap["counters"], snap["spans"]
+    trip = max(cfg.max_steps - r.fuel_left for r in results)
+    assert trip >= max(r.steps for r in results)
+    assert counters == {
+        "lane_step.rows": 5,
+        "lane_step.row_iterations": 5 * trip,
+        "lane_step.useful_steps": sum(r.steps for r in results)}
+    for name in ("sim.run_batch", "sim.pack", "sim.lane_step",
+                 "sim.assemble"):
+        assert spans[name]["n"] == 1, name
+    assert spans["sim.run_batch"]["self_s"] < spans["sim.run_batch"][
+        "total_s"]
+    for r in results:
+        assert r.wall_time_s == pytest.approx(
+            spans["sim.lane_step"]["total_s"] / 5)
+        if "compile_time_s" in r.meta:       # this shape compiled here
+            assert r.meta["compile_time_s"] == pytest.approx(
+                spans["sim.compile"]["total_s"])
+
+
+def test_lane_step_program_keeps_its_module_name():
+    """Profiler traces name the lane step's device program by its XLA
+    module, ``jit_one``: a refactor must not rename it unseen."""
+    from repro.engine.adapters import _compiled_batch_exec
+    compiled, _ = _compiled_batch_exec(CFG, True, 2, 32)
+    assert compiled.as_text().startswith("HloModule jit_one,")

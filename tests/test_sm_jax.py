@@ -281,6 +281,65 @@ def test_hanoi_jax_compile_time_metered_separately():
 
 
 # ---------------------------------------------------------------------------
+# the grid's spans and lane-step / scheduler counters (repro.obs)
+# ---------------------------------------------------------------------------
+
+def test_grid_feeds_the_lane_step_and_scheduler_counters():
+    """A grid counts the lane step's rows (padding included), its loop trip
+    count and the distinct rows' steps, and the scheduler's scanned and
+    issued cell-slots; each cell's wall time is its share of the lane-step
+    and scheduler spans."""
+    from repro import obs
+    from repro.engine.mechanisms.sm_jax import (_batch_class, _out_capacity,
+                                                run_cells)
+    a, b, c = (SimRequest(program=BENCH[n].program, cfg=CFG,
+                          init_mem=BENCH[n].init_mem, name=n)
+               for n in ("DIAMOND", "HOTS0", "GAUS0"))
+    cells = [[a, b], [b, c], [a, a]]
+    obs.reset()
+    obs.enable()
+    try:
+        sms = run_cells(cells, policy="greedy_then_oldest")
+    finally:
+        obs.disable()
+    snap = obs.snapshot()
+    obs.reset()
+    counters, spans = snap["counters"], snap["spans"]
+    distinct = [sms[0].warps[0], sms[0].warps[1], sms[1].warps[1]]
+    rows = _batch_class(3)
+    trip = max(CFG.max_steps - w.fuel_left for w in distinct)
+    cap = _out_capacity(max(sum(len(w.trace) for w in sm.warps)
+                            for sm in sms))
+    assert counters == {
+        "lane_step.rows": rows,
+        "lane_step.row_iterations": rows * trip,
+        "lane_step.useful_steps": sum(w.steps for w in distinct),
+        "schedule.slots_scanned": len(cells) * cap,
+        "schedule.slots_issued": sum(sm.steps for sm in sms)}
+    want = {"sim.run_cells": 1, "sim.pack": 2, "sim.lane_step": 1,
+            "sim.schedule": 1, "sim.assemble": 2}
+    assert {n: spans[n]["n"] for n in want} == want
+    device_s = spans["sim.lane_step"]["total_s"] + \
+        spans["sim.schedule"]["total_s"]
+    for sm in sms:
+        assert sm.wall_time_s == pytest.approx(device_s / len(cells))
+        if "compile_time_s" in sm.meta:      # a shape compiled here
+            assert sm.meta["compile_time_s"] == pytest.approx(
+                spans["sim.compile"]["total_s"])
+
+
+def test_scheduler_program_keeps_its_module_name():
+    """Profiler traces name the issue scheduler's device program by its XLA
+    module, ``jit_schedule``: a refactor must not rename it unseen."""
+    from repro.engine.mechanisms.sm_jax import (_POLICY_IDS,
+                                                _compiled_grid_scheduler)
+    compiled, _ = _compiled_grid_scheduler(
+        2, 2, 8, 64, 32, 256, _POLICY_IDS["greedy_then_oldest"],
+        (2, 1, 30, 40))
+    assert compiled.as_text().startswith("HloModule jit_schedule,")
+
+
+# ---------------------------------------------------------------------------
 # satellite: warp_count sized-sequence contract + service stats parity
 # ---------------------------------------------------------------------------
 
