@@ -137,9 +137,22 @@ def padded_len(n: int) -> int:
     return -(-n // PAD_QUANTUM) * PAD_QUANTUM
 
 
+def _fetch_states(states):
+    """Bring a (batched) ``HanoiState`` to the host in one call.
+
+    ``jax.device_get`` starts every leaf's copy before it waits on any, so
+    a batch costs one round trip; its rows are then numpy views, and
+    assembling a result from one touches the device no more."""
+    import jax
+    return jax.device_get(states)
+
+
 def _jax_result(req: SimRequest, state, wall_time_s: float,
                 mechanism: str = "hanoi_jax",
                 meta: "dict | None" = None) -> SimResult:
+    """The ``SimResult`` of one warp's host-side (numpy) ``HanoiState``.
+    ``regs``, ``preds`` and ``mem`` are copies, so a result never aliases
+    or pins the batch it came from."""
     from repro.core.hanoi import ERR_NO_FREE_BX, state_trace
     cfg = req.resolved_cfg()
     err_flags = int(state.error)
@@ -152,8 +165,8 @@ def _jax_result(req: SimRequest, state, wall_time_s: float,
         status=classify_status(finished=int(state.finished),
                                full_mask=cfg.full_mask,
                                fuel_left=fuel_left, error=error),
-        regs=np.asarray(state.regs), preds=np.asarray(state.preds),
-        mem=np.asarray(state.mem), finished=int(state.finished),
+        regs=np.array(state.regs), preds=np.array(state.preds),
+        mem=np.array(state.mem), finished=int(state.finished),
         steps=int(state.steps), fuel_left=fuel_left, trace=trace,
         utilization=simd_utilization(list(trace), cfg.n_threads),
         error=error, wall_time_s=wall_time_s, meta=meta or {})
@@ -419,10 +432,10 @@ def _run_hanoi_jax_batch(reqs: Sequence[SimRequest]) -> list[SimResult]:
     wall = lane.seconds / max(1, len(reqs))
     meta = {"compile_time_s": compile_s} if compile_s is not None else None
     with obs.span("sim.assemble"):
-        per_warp = [jax.tree_util.tree_map(lambda x, i=i: x[i], states)
-                    for i in range(len(reqs))]
-        results = [_jax_result(r, st, wall, meta=meta)
-                   for r, st in zip(reqs, per_warp)]
+        host = _fetch_states(states)
+        results = [_jax_result(r, jax.tree_util.tree_map(
+                       lambda x, i=i: x[i], host), wall, meta=meta)
+                   for i, r in enumerate(reqs)]
     if obs.enabled():
         _count_lane_step(cfg, [r.steps for r in results],
                         [r.fuel_left for r in results])
@@ -461,4 +474,5 @@ def _run_hanoi_jax(req: SimRequest) -> SimResult:
         pad_to=padded_len(int(np.asarray(req.program).shape[0])))
     import jax
     jax.block_until_ready(state.regs)
-    return _jax_result(req, state, time.perf_counter() - t0)
+    wall = time.perf_counter() - t0
+    return _jax_result(req, _fetch_states(state), wall)

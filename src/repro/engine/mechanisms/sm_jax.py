@@ -47,7 +47,7 @@ from repro.timing.policies import POLICY_NAMES, resolve_policy_name
 from repro.timing.sm_model import _CONTROL_LAT_OPS
 
 from ..adapters import _batch_arrays, _compiled_batch_exec, _jax_result, \
-    _count_lane_step, padded_len
+    _count_lane_step, _fetch_states, padded_len
 from ..registry import get_mechanism, register_mechanism
 from ..types import SimRequest, SimResult, SmResult, worst_status
 from .sm import DEFAULT_POLICY, _sm_options
@@ -351,7 +351,7 @@ def run_cells(cells: Sequence[Sequence[SimRequest]], *,
         exec_s = lane.seconds
         dev_pc, dev_mask = states.trace_pc, states.trace_mask  # on device
         with obs.span("sim.assemble"):
-            states = jax.tree_util.tree_map(np.asarray, states)
+            states = _fetch_states(states)
         if obs.enabled():
             # rows past len(first) repeat row 0: padding, not useful work
             _count_lane_step(cfg, states.steps[:len(first)], states.fuel)

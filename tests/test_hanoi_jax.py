@@ -205,3 +205,117 @@ def test_lane_step_program_keeps_its_module_name():
     from repro.engine.adapters import _compiled_batch_exec
     compiled, _ = _compiled_batch_exec(CFG, True, 2, 32)
     assert compiled.as_text().startswith("HloModule jit_one,")
+
+
+# ---------------------------------------------------------------------------
+# result assembly: one bulk copy per batch, then numpy rows
+# ---------------------------------------------------------------------------
+
+NO_FREE_BX_ASM = """
+    BSSY B0, s0
+    BSSY B1, s1
+    WARPSYNC 15
+    MOV R3, 9
+s1:
+    BSYNC B1
+s0:
+    BSYNC B0
+    EXIT
+"""
+
+SPIN_FOREVER_ASM = """
+top:
+    IADDI R1, R1, 1
+    BRA top
+"""
+
+# a diamond whose arms make it longer than one padding quantum
+LONG_DIAMOND_ASM = (
+    "    LANEID R1\n    BSSY B0, sync\n    ISETP.LT P0, R1, 2\n"
+    "    @P0 BRA taken\n" + "    IADDI R2, R2, 3\n" * 16 +
+    "    BRA sync\ntaken:\n" + "    IADDI R3, R3, 5\n" * 16 +
+    "sync:\n    BSYNC B0\n    STG [R1], R2\n    EXIT\n")
+
+
+def _mixed_batch():
+    """Requests for one device batch: programs of two padding classes, a
+    warp that runs out of fuel, one whose WARPSYNC finds no free Bx, and
+    ``record_trace`` on and off.  The planner would split them by
+    signature; the batch runner takes them as one batch."""
+    from repro.core.asm import assemble
+    from repro.engine import SimRequest, SimStatus
+    from repro.engine.adapters import padded_len
+    cfg = MachineConfig(n_threads=4, n_bx=2, mem_size=48, max_steps=1536)
+    short = next(b for b in make_suite(cfg, datasets=2) if b.name == "LUD0")
+    long_ = assemble(LONG_DIAMOND_ASM)
+    assert padded_len(len(short.program)) == 32
+    assert padded_len(len(long_)) == 64
+    reqs = [
+        SimRequest(program=short.program, cfg=cfg, init_mem=short.init_mem),
+        SimRequest(program=long_, cfg=cfg, record_trace=False),
+        SimRequest(program=long_, cfg=cfg),
+        SimRequest(program=assemble(SPIN_FOREVER_ASM), cfg=cfg),
+        SimRequest(program=assemble(NO_FREE_BX_ASM), cfg=cfg),
+        SimRequest(program=fig5_program(), cfg=cfg, record_trace=False),
+    ]
+    ref = [Simulator("hanoi").run(r) for r in reqs]
+    assert ref[3].status is SimStatus.OUT_OF_FUEL
+    assert ref[4].error == "WARPSYNC: no free Bx register"
+    return reqs, ref
+
+
+def _assert_same_result(got, want):
+    for f in ("status", "finished", "steps", "fuel_left", "trace",
+              "utilization", "error"):
+        assert getattr(got, f) == getattr(want, f), f
+    for f in ("regs", "preds", "mem"):
+        a, b = getattr(got, f), getattr(want, f)
+        assert a.dtype == b.dtype and a.shape == b.shape, f
+        np.testing.assert_array_equal(a, b, err_msg=f)
+
+
+def test_batch_results_match_single_requests_and_numpy():
+    """Every field of a mixed batch's results equals the single-request
+    path's and the numpy Hanoi reference's (mechanism, wall time and
+    compile meta aside)."""
+    from repro.engine.adapters import _run_hanoi_jax_batch
+    reqs, ref = _mixed_batch()
+    batch = _run_hanoi_jax_batch(reqs)
+    one = Simulator("hanoi_jax")
+    for req, got, want in zip(reqs, batch, ref):
+        single = one.run(req)
+        assert got.mechanism == single.mechanism == "hanoi_jax"
+        _assert_same_result(got, want)
+        _assert_same_result(single, want)
+
+
+def test_batch_copies_to_the_host_once_and_assembles_numpy(monkeypatch):
+    """A batch brings its states to the host in one call; every leaf a
+    result is built from is numpy, and no result shares memory with
+    another or with the fetched batch."""
+    from repro.engine import adapters
+    reqs, _ = _mixed_batch()
+    fetches, seen = [], []
+    fetch, build = adapters._fetch_states, adapters._jax_result
+
+    def counting_fetch(states):
+        fetches.append(fetch(states))
+        return fetches[-1]
+
+    def checking_build(req, state, *a, **kw):
+        seen.append([type(x) for x in state])
+        return build(req, state, *a, **kw)
+
+    monkeypatch.setattr(adapters, "_fetch_states", counting_fetch)
+    monkeypatch.setattr(adapters, "_jax_result", checking_build)
+    results = adapters._run_hanoi_jax_batch(reqs)
+    assert len(fetches) == 1
+    assert len(seen) == len(reqs)
+    for types in seen:
+        assert all(issubclass(t, (np.ndarray, np.generic)) for t in types)
+    [host] = fetches
+    for i, a in enumerate(results):
+        for f in ("regs", "preds", "mem"):
+            assert not np.shares_memory(getattr(a, f), getattr(host, f)), f
+            for b in results[i + 1:]:
+                assert not np.shares_memory(getattr(a, f), getattr(b, f)), f
