@@ -39,9 +39,9 @@ from repro.core.dualpath import run_dual_path
 from .registry import register_mechanism
 from .types import SimRequest, SimResult, classify_status
 
-__all__ = ["PAD_QUANTUM", "padded_len", "result_from_runresult",
-           "batch_cache_stats", "reset_batch_caches",
-           "set_batch_cache_capacity"]
+__all__ = ["PAD_QUANTUM", "padded_len", "batch_class",
+           "result_from_runresult", "batch_cache_stats",
+           "reset_batch_caches", "set_batch_cache_capacity"]
 
 
 def result_from_runresult(mechanism: str, r: RunResult, req: SimRequest,
@@ -137,6 +137,13 @@ def padded_len(n: int) -> int:
     return -(-n // PAD_QUANTUM) * PAD_QUANTUM
 
 
+def batch_class(n: int) -> int:
+    """The batch class of ``n`` lane-step rows: the next power of two at or
+    above ``n`` (at least 1).  A batch is padded to its class, so batches
+    of 1..64 rows share 7 executables per (cfg, padding class)."""
+    return 1 << max(0, int(n) - 1).bit_length()
+
+
 def _fetch_states(states):
     """Bring a (batched) ``HanoiState`` to the host in one call.
 
@@ -223,8 +230,8 @@ def _jitted_batch_runner(cfg, majority_first: bool):
     majority_first).  The jit boundary is essential for service throughput:
     a bare ``jax.vmap(one)`` re-traces the whole state machine on *every*
     batch call (slower than the per-request path, whose inner ``_run`` jit
-    caches), whereas this callable re-traces only per new (batch size,
-    padded length) shape and then replays the cached executable."""
+    caches), whereas this callable re-traces only per new (batch class,
+    padding class) shape and then replays the cached executable."""
     key = (cfg, bool(majority_first))
     with _BATCH_CACHE_LOCK:
         fn = _JITTED_RUNNERS.get(key)
@@ -249,8 +256,7 @@ def _batch_arrays(reqs: Sequence[SimRequest], cfg, pad_len: int
                   ) -> tuple[np.ndarray, ...]:
     """``(progs, skips, regs, mems, lanes)`` operand arrays for one
     signature-homogeneous batch, programs padded with unreachable EXITs to
-    ``pad_len``.  Shared by the hanoi_jax batch runner and the sm_jax
-    per-warp phase."""
+    ``pad_len``."""
     from repro.core.isa import Op
 
     W = cfg.n_threads
@@ -271,6 +277,30 @@ def _batch_arrays(reqs: Sequence[SimRequest], cfg, pad_len: int
         if r.lane_ids is not None:
             lanes[i] = np.asarray(r.lane_ids, np.int32).reshape(W)
     return progs, skips, regs, mems, lanes
+
+
+def _dedupe_rows(progs: np.ndarray, skips: np.ndarray, regs: np.ndarray,
+                 mems: np.ndarray, lanes: np.ndarray
+                 ) -> tuple[np.ndarray, np.ndarray]:
+    """Hash-cons warp rows: ``(first, inv)`` with ``first`` the indices of
+    the unique rows (in first-seen order) and ``inv[i]`` the unique slot of
+    row ``i``.  Execution is a pure function of the row operands (the
+    resolved config and ``majority_first`` are batch-wide), so identical
+    rows — N replicated warps of a cell, repeated cells of a grid — run
+    the lane program once and share one result."""
+    uniq: dict[bytes, int] = {}
+    first: list[int] = []
+    inv = np.empty(progs.shape[0], np.int64)
+    for i in range(progs.shape[0]):
+        key = (progs[i].tobytes() + skips[i].tobytes() + regs[i].tobytes()
+               + mems[i].tobytes() + lanes[i].tobytes())
+        u = uniq.get(key)
+        if u is None:
+            u = len(first)
+            uniq[key] = u
+            first.append(i)
+        inv[i] = u
+    return np.asarray(first, np.int64), inv
 
 
 # AOT-compiled executables keyed by (cfg, majority_first, batch, pad_len).
@@ -328,7 +358,7 @@ def set_batch_cache_capacity(executables: int | None = None,
 
 def _compiled_batch_exec(cfg, majority_first: bool, batch: int, pad_len: int):
     """``(compiled executable, fresh compile seconds | None)`` for one
-    (cfg, majority_first, batch-size, padding-class) shape signature.
+    (cfg, majority_first, batch-class, padding-class) shape signature.
 
     Lookup order: in-memory LRU -> installed persistent compile cache
     (deserialized AOT executable, no trace) -> fresh AOT trace+compile
@@ -399,13 +429,47 @@ def _compiled_batch_exec(cfg, majority_first: bool, batch: int, pad_len: int):
     return compiled, compile_s
 
 
-def _run_hanoi_jax_batch(reqs: Sequence[SimRequest]) -> list[SimResult]:
-    """Native batched execution: vmap over warps AND over (padded) programs.
+def _run_lane_step(reqs: Sequence[SimRequest]):
+    """Run one signature's requests through the lane step, each distinct
+    row once: the batching path of ``hanoi_jax`` and ``sm_jax``.
 
-    All requests must share cfg / majority_first / active0=None (the
-    planner's execution signature guarantees it before dispatching here).
-    Programs of different lengths are padded with unreachable EXITs to one
-    shape so a single compiled executable serves the whole batch.
+    Requests must share cfg / majority_first / active0=None.  Rows are
+    packed, hash-consed and padded to their :func:`batch_class` by
+    repeating the first.  Returns ``(host, inv, progs, trace, lane_s,
+    compile_s)``: the batch's host states (unique rows first), ``inv[i]``
+    the row of ``reqs[i]``, every request's packed program, the device's
+    ``(trace_pc, trace_mask)`` (the rest of the device states is freed on
+    return), the ``sim.lane_step`` seconds and the fresh compile's seconds
+    (``None`` on a cache hit).
+    """
+    import jax
+    import jax.numpy as jnp
+
+    cfg = reqs[0].resolved_cfg()
+    with obs.span("sim.pack"):
+        L = padded_len(max(int(np.asarray(r.program).shape[0])
+                           for r in reqs))
+        arrays = _batch_arrays(reqs, cfg, L)
+        first, inv = _dedupe_rows(*arrays)
+        sel = np.concatenate([first, np.full(
+            batch_class(len(first)) - len(first), first[0], np.int64)])
+        rows = [a[sel] for a in arrays]
+    compiled, compile_s = _compiled_batch_exec(cfg, reqs[0].majority_first,
+                                               len(sel), L)
+    with obs.span("sim.lane_step") as lane:
+        states = compiled(*(jnp.asarray(a) for a in rows))
+        jax.block_until_ready(states.regs)
+    with obs.span("sim.assemble"):
+        host = _fetch_states(states)
+    if obs.enabled():
+        # rows past len(first) repeat row 0: padding, not useful work
+        _count_lane_step(cfg, host.steps[:len(first)], host.fuel)
+    return (host, inv, arrays[0], (states.trace_pc, states.trace_mask),
+            lane.seconds, compile_s)
+
+
+def _run_hanoi_jax_batch(reqs: Sequence[SimRequest]) -> list[SimResult]:
+    """Native batched execution through :func:`_run_lane_step`.
 
     Wall-time accounting: ``wall_time_s`` is execution-only, amortized per
     request.  A fresh XLA compile (first batch per shape signature) is
@@ -413,38 +477,19 @@ def _run_hanoi_jax_batch(reqs: Sequence[SimRequest]) -> list[SimResult]:
     batch's results — it never inflates latency percentiles.
     """
     import jax
-    import jax.numpy as jnp
 
-    cfg = reqs[0].resolved_cfg()
-    majority_first = reqs[0].majority_first
-    with obs.span("sim.pack"):
-        L = padded_len(max(int(np.asarray(r.program).shape[0])
-                           for r in reqs))
-        progs, skips, regs, mems, lanes = _batch_arrays(reqs, cfg, L)
-
-    compiled, compile_s = _compiled_batch_exec(cfg, majority_first,
-                                               len(reqs), L)
-    with obs.span("sim.lane_step") as lane:
-        states = compiled(jnp.asarray(progs), jnp.asarray(skips),
-                          jnp.asarray(regs), jnp.asarray(mems),
-                          jnp.asarray(lanes))
-        jax.block_until_ready(states.regs)
-    wall = lane.seconds / max(1, len(reqs))
+    host, inv, _, _, lane_s, compile_s = _run_lane_step(reqs)
+    wall = lane_s / len(reqs)
     meta = {"compile_time_s": compile_s} if compile_s is not None else None
     with obs.span("sim.assemble"):
-        host = _fetch_states(states)
-        results = [_jax_result(r, jax.tree_util.tree_map(
-                       lambda x, i=i: x[i], host), wall, meta=meta)
-                   for i, r in enumerate(reqs)]
-    if obs.enabled():
-        _count_lane_step(cfg, [r.steps for r in results],
-                        [r.fuel_left for r in results])
-    return results
+        return [_jax_result(r, jax.tree_util.tree_map(
+                    lambda x, u=u: x[u], host), wall, meta=meta)
+                for r, u in zip(reqs, inv)]
 
 
 def _count_lane_step(cfg, steps, fuel_left) -> None:
     """Feed the lane-step counters for one executed batch (every row,
-    padding included; ``steps`` only for the rows that are not padding).
+    padding included; ``steps`` only for the distinct rows).
 
     ``lane_step.row_iterations`` is rows x the batch's ``while_loop`` trip
     count.  Each iteration spends one unit of fuel, executing an

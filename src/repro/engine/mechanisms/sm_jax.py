@@ -6,9 +6,10 @@ the same SM model as a lane-parallel state machine so it runs in array
 land end to end, in two fused device programs:
 
 1. **warp phase** — every warp of every cell executes the paper's Hanoi
-   mechanism through the *same* cached ``jit(vmap)`` batch executable the
-   ``hanoi_jax`` service path uses (:func:`repro.engine.adapters.
-   _compiled_batch_exec`, one row per warp, programs padded to their
+   mechanism through the *same* batching path and cached ``jit(vmap)``
+   executable as ``hanoi_jax`` (:func:`repro.engine.adapters.
+   _run_lane_step`: one row per distinct warp, padded to its
+   :func:`~repro.engine.adapters.batch_class`, programs to their
    :func:`~repro.engine.adapters.padded_len` class);
 2. **scheduler phase** — one ``lax.while_loop`` steps an entire N-warp SM:
    per-warp trace cursors, completion times and memory-blocked flags are
@@ -46,8 +47,10 @@ from repro.timing import CycleConfig
 from repro.timing.policies import POLICY_NAMES, resolve_policy_name
 from repro.timing.sm_model import _CONTROL_LAT_OPS
 
-from ..adapters import _batch_arrays, _compiled_batch_exec, _jax_result, \
-    _count_lane_step, _fetch_states, padded_len
+from ..adapters import _jax_result, _run_lane_step
+# the benchmark's shape rehearsal and fault tests find these here
+from ..adapters import _compiled_batch_exec, _dedupe_rows  # noqa: F401
+from ..adapters import batch_class as _batch_class  # noqa: F401
 from ..registry import get_mechanism, register_mechanism
 from ..types import SimRequest, SimResult, SmResult, worst_status
 from .sm import DEFAULT_POLICY, _sm_options
@@ -102,37 +105,6 @@ def _out_capacity(n: int) -> int:
     """Issue-slot capacity class: power of two with a floor, so the
     scheduler recompiles per coarse trace-volume class, not per cell."""
     return max(256, 1 << max(0, int(n) - 1).bit_length())
-
-
-def _batch_class(n: int) -> int:
-    """Batch-size padding class (power of two, floor 8) for the unique-row
-    warp phase — bounds recompiles the same way ``padded_len`` does for
-    program length."""
-    return max(8, 1 << max(0, int(n) - 1).bit_length())
-
-
-def _dedupe_rows(progs: np.ndarray, skips: np.ndarray, regs: np.ndarray,
-                 mems: np.ndarray, lanes: np.ndarray
-                 ) -> tuple[np.ndarray, np.ndarray]:
-    """Hash-cons warp rows: ``(first, inv)`` with ``first`` the indices of
-    the unique rows (in first-seen order) and ``inv[i]`` the unique slot of
-    row ``i``.  Execution is a pure function of the row operands (the
-    resolved config and ``majority_first`` are grid-wide), so identical
-    rows — N replicated warps of a cell, repeated cells of a grid — run
-    the lane program once and share one result."""
-    uniq: dict[bytes, int] = {}
-    first: list[int] = []
-    inv = np.empty(progs.shape[0], np.int64)
-    for i in range(progs.shape[0]):
-        key = (progs[i].tobytes() + skips[i].tobytes() + regs[i].tobytes()
-               + mems[i].tobytes() + lanes[i].tobytes())
-        u = uniq.get(key)
-        if u is None:
-            u = len(first)
-            uniq[key] = u
-            first.append(i)
-        inv[i] = u
-    return np.asarray(first, np.int64), inv
 
 
 def _cell_scheduler(n_warps: int, out_cap: int, policy_id: int,
@@ -330,37 +302,18 @@ def run_cells(cells: Sequence[Sequence[SimRequest]], *,
         import jax
         import jax.numpy as jnp
 
-        # phase 1: hash-cons the warp rows — identical (program, skips,
-        # regs, mem, lanes) rows execute ONCE through the shared hanoi batch
-        # executable (same compile cache as the hanoi_jax service path).
-        # The replicated-warp path collapses N identical warps per cell to
-        # one row, so a whole grid costs #unique-programs lane executions.
-        with obs.span("sim.pack"):
-            L = padded_len(max(int(np.asarray(q.program).shape[0])
-                               for q in flat))
-            progs, skips, regs, mems, lanes = _batch_arrays(flat, cfg, L)
-            first, inv = _dedupe_rows(progs, skips, regs, mems, lanes)
-            n_uniq = _batch_class(len(first))         # batch-size class
-            sel = np.concatenate([first, np.full(n_uniq - len(first),
-                                                 first[0], dtype=np.int64)])
-            rows = [a[sel] for a in (progs, skips, regs, mems, lanes)]
-        compiled, compile_s = _compiled_batch_exec(cfg, mf, n_uniq, L)
-        with obs.span("sim.lane_step") as lane:
-            states = compiled(*(jnp.asarray(a) for a in rows))
-            jax.block_until_ready(states.regs)
-        exec_s = lane.seconds
-        dev_pc, dev_mask = states.trace_pc, states.trace_mask  # on device
-        with obs.span("sim.assemble"):
-            states = _fetch_states(states)
-        if obs.enabled():
-            # rows past len(first) repeat row 0: padding, not useful work
-            _count_lane_step(cfg, states.steps[:len(first)], states.fuel)
+        # phase 1: every distinct warp row once through the lane step
+        # shared with hanoi_jax; N replicated warps of a cell collapse to
+        # one row, so a grid costs #unique-programs lane executions
+        host, inv, progs, (dev_pc, dev_mask), exec_s, compile_s = \
+            _run_lane_step(flat)
+        L, n_uniq = progs.shape[1], host.steps.shape[0]
 
         C, N, T = len(cells), n_warps, cfg.max_steps
         total_compile = compile_s or 0.0
         with obs.span("sim.pack"):
             warp_map = inv.reshape(C, N).astype(np.int32)
-            trace_n = states.trace_n[inv].reshape(C, N).astype(np.int32)
+            trace_n = host.trace_n[inv].reshape(C, N).astype(np.int32)
             scheduled = bool(record) and int(trace_n.max(initial=0)) > 0
             if scheduled:
                 ops = progs[:, :, F_OP].reshape(C, N, L)
@@ -396,10 +349,11 @@ def run_cells(cells: Sequence[Sequence[SimRequest]], *,
             # one SimResult per unique row, shared by every warp that
             # hash-consed onto it (SimResult is frozen; SmResult.requests
             # keeps per-warp names)
+            first = np.unique(inv, return_index=True)[1]
             uniq_results = [
                 _jax_result(flat[int(first[u])],
                             jax.tree_util.tree_map(lambda x, u=u: x[u],
-                                                   states),
+                                                   host),
                             warp_wall)
                 for u in range(len(first))]
             sms: list[SmResult] = []

@@ -7,7 +7,8 @@ machine closes over is equal: the mechanism, the resolved
 *padding class* (length rounded up to
 :data:`~repro.engine.adapters.PAD_QUANTUM` — programs in one class batch
 into the same padded shape), the scheduling options
-(``majority_first``), the oracle skip set, and any mechanism-specific
+(``majority_first``), the oracle skip set of a mechanism that reads it
+(``Mechanism.uses_skip_pcs``), and any mechanism-specific
 ``meta`` options.  Per-request *data* — registers, memory image, lane ids —
 is deliberately **not** part of the signature: the batch runner carries it
 as vmapped operands.
@@ -95,14 +96,14 @@ def shard_of(sig: ExecSignature, n_shards: int) -> int:
 
 def signature_of(mechanism: "str | Mechanism", req: SimRequest) -> ExecSignature:
     """Derive the coalescing/dispatch signature of one request."""
-    name = mechanism.name if isinstance(mechanism, Mechanism) \
-        else get_mechanism(mechanism).name
+    mech = mechanism if isinstance(mechanism, Mechanism) \
+        else get_mechanism(mechanism)
     return ExecSignature(
-        mechanism=name,
+        mechanism=mech.name,
         cfg=req.resolved_cfg(),
         pad_len=padded_len(int(np.asarray(req.program).shape[0])),
         majority_first=bool(req.majority_first),
         batchable=req.active0 is None,
         record_trace=bool(req.record_trace),
-        skip_pcs=tuple(req.bsync_skip_pcs),
+        skip_pcs=tuple(req.bsync_skip_pcs) if mech.uses_skip_pcs else (),
         meta=meta_key(req.meta))

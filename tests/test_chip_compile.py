@@ -19,17 +19,16 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core.isa import MachineConfig
-from repro.engine.adapters import _jitted_batch_runner
-from repro.engine.mechanisms.sm_jax import (_GTO, _batch_class,
-                                            _cell_scheduler, _latency_tables,
-                                            _out_capacity)
+from repro.engine.adapters import _jitted_batch_runner, batch_class
+from repro.engine.mechanisms.sm_jax import (_GTO, _cell_scheduler,
+                                            _latency_tables, _out_capacity)
 from repro.timing import CycleConfig
 
 HBM_BYTES = 16 * 10**9            # one TPU v5e chip
 CFG = MachineConfig(n_threads=32, max_steps=8192)
 N_CELLS, N_WARPS = 68, 32         # 68 SMs (TU102) x 32 resident warps
 PAD_LEN = 32                      # the suite's padding class
-N_ROWS = _batch_class(N_CELLS * N_WARPS)
+N_ROWS = batch_class(N_CELLS * N_WARPS)
 OUT_CAP = _out_capacity(N_WARPS * CFG.max_steps)
 
 

@@ -164,7 +164,7 @@ def test_batch_feeds_the_lane_step_counters_and_spans():
     a step) and the rows' steps; its wall time is the lane-step span's."""
     from repro import obs
     from repro.engine import SimRequest
-    from repro.engine.adapters import padded_len
+    from repro.engine.adapters import batch_class, padded_len
     cfg = MachineConfig(n_threads=4, mem_size=48, max_steps=1536)
     suite = [b for b in make_suite(cfg, datasets=2)
              if padded_len(len(b.program)) == 32][:5]
@@ -182,13 +182,14 @@ def test_batch_feeds_the_lane_step_counters_and_spans():
     counters, spans = snap["counters"], snap["spans"]
     trip = max(cfg.max_steps - r.fuel_left for r in results)
     assert trip >= max(r.steps for r in results)
+    rows = batch_class(5)                 # 5 distinct rows + 3 of padding
     assert counters == {
-        "lane_step.rows": 5,
-        "lane_step.row_iterations": 5 * trip,
+        "lane_step.rows": rows,
+        "lane_step.row_iterations": rows * trip,
         "lane_step.useful_steps": sum(r.steps for r in results)}
-    for name in ("sim.run_batch", "sim.pack", "sim.lane_step",
-                 "sim.assemble"):
-        assert spans[name]["n"] == 1, name
+    want = {"sim.run_batch": 1, "sim.pack": 1, "sim.lane_step": 1,
+            "sim.assemble": 2}            # the bulk copy, then the results
+    assert {n: spans[n]["n"] for n in want} == want
     assert spans["sim.run_batch"]["self_s"] < spans["sim.run_batch"][
         "total_s"]
     for r in results:
@@ -197,6 +198,37 @@ def test_batch_feeds_the_lane_step_counters_and_spans():
         if "compile_time_s" in r.meta:       # this shape compiled here
             assert r.meta["compile_time_s"] == pytest.approx(
                 spans["sim.compile"]["total_s"])
+
+
+@pytest.mark.parametrize("n,cls", [(0, 1), (1, 1), (2, 2), (3, 4), (4, 4),
+                                   (5, 8), (16, 16), (17, 32), (63, 64),
+                                   (64, 64), (65, 128), (2176, 4096)])
+def test_batch_class(n, cls):
+    from repro.engine.adapters import batch_class
+    assert batch_class(n) == cls
+
+
+def test_run_batch_compiles_one_executable_per_batch_class():
+    """Batches of 1..17 requests under one signature run on the executables
+    of their batch classes (1, 2, 4, 8, 16, 32), each compiled once, and
+    every warp is bit-identical to numpy Hanoi.  BFSD carries oracle skip
+    pcs, which hanoi_jax ignores: it joins the others' batch."""
+    from repro.engine import as_request
+    from repro.engine.adapters import batch_cache_stats, batch_class
+    cfg = MachineConfig(n_threads=4, mem_size=48, max_steps=1280)  # own key
+    reqs = [as_request(b, cfg) for b in make_suite(cfg, datasets=3)[:17]]
+    assert any(r.bsync_skip_pcs for r in reqs)
+    ref = [SIM.run(r) for r in reqs]
+    sim, seen = Simulator("hanoi_jax"), set()
+    for n in range(1, 18):
+        before = batch_cache_stats()["misses"]
+        got = sim.run_batch(reqs[:n])
+        new = batch_class(n) not in seen
+        seen.add(batch_class(n))
+        assert batch_cache_stats()["misses"] - before == int(new), n
+        for g, w in zip(got, ref):
+            _assert_same_result(g, w)
+    assert seen == {1, 2, 4, 8, 16, 32}
 
 
 def test_lane_step_program_keeps_its_module_name():

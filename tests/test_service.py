@@ -65,12 +65,36 @@ def test_signature_groups_compatible_requests():
     (dict(meta={"itps_patience": 1}), "meta"),
 ])
 def test_signature_splits_on(override, field):
-    base = signature_of("hanoi", as_request(_bench("DIAMOND"), CFG))
+    # turing_oracle reads every field, the skip pcs included
+    base = signature_of("turing_oracle", as_request(_bench("DIAMOND"), CFG))
     cfg = override.pop("cfg", CFG)
-    changed = signature_of("hanoi", as_request(_bench("DIAMOND"), cfg,
-                                               **override))
+    changed = signature_of("turing_oracle", as_request(
+        _bench("DIAMOND"), cfg, **override))
     assert base != changed
     assert getattr(base, field) != getattr(changed, field)
+
+
+@pytest.mark.parametrize("mechanism,groups", [("hanoi_jax", 1),
+                                              ("turing_oracle", 2)])
+def test_skip_pcs_split_only_a_mechanism_that_reads_them(mechanism, groups):
+    """BFSD carries oracle skip pcs.  hanoi_jax ignores them, so BFSD joins
+    the others' batch and runs on one executable, matching numpy Hanoi;
+    turing_oracle reads them and keeps BFSD apart."""
+    from repro.engine.adapters import batch_cache_stats
+    reqs = [as_request(_bench(n), CFG) for n in ("DIAMOND", "BFSD", "GAUS0")]
+    assert reqs[1].bsync_skip_pcs and not reqs[0].bsync_skip_pcs
+    mech = get_mechanism(mechanism)
+    assert len(plan_dispatch(mech, reqs)) == groups
+    ref = "hanoi" if mechanism == "hanoi_jax" else mechanism
+    def lookups():
+        s = batch_cache_stats()
+        return s["hits"] + s["misses"] + s["disk_hits"]
+
+    before = lookups()
+    got = Simulator(mechanism).run_batch(reqs)
+    assert lookups() - before == (1 if mechanism == "hanoi_jax" else 0)
+    for req, res in zip(reqs, got):
+        _same_outcome(res, Simulator(ref).run(req))
 
 
 def test_signature_pad_class():

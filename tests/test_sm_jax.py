@@ -290,8 +290,8 @@ def test_grid_feeds_the_lane_step_and_scheduler_counters():
     issued cell-slots; each cell's wall time is its share of the lane-step
     and scheduler spans."""
     from repro import obs
-    from repro.engine.mechanisms.sm_jax import (_batch_class, _out_capacity,
-                                                run_cells)
+    from repro.engine.adapters import batch_class
+    from repro.engine.mechanisms.sm_jax import _out_capacity, run_cells
     a, b, c = (SimRequest(program=BENCH[n].program, cfg=CFG,
                           init_mem=BENCH[n].init_mem, name=n)
                for n in ("DIAMOND", "HOTS0", "GAUS0"))
@@ -306,7 +306,7 @@ def test_grid_feeds_the_lane_step_and_scheduler_counters():
     obs.reset()
     counters, spans = snap["counters"], snap["spans"]
     distinct = [sms[0].warps[0], sms[0].warps[1], sms[1].warps[1]]
-    rows = _batch_class(3)
+    rows = batch_class(3)
     trip = max(CFG.max_steps - w.fuel_left for w in distinct)
     cap = _out_capacity(max(sum(len(w.trace) for w in sm.warps)
                             for sm in sms))
