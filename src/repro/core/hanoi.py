@@ -140,6 +140,58 @@ def _cmp(a, b, code):
         lambda: a <= b, lambda: a > b, lambda: a >= b])
 
 
+def _lane_serial_mem(op, mem, regs, ev, s0, s1, s2, imm):
+    """One warp's STG or atomic (CAS, EXCH, ADD), lane-parallel.
+
+    Returns ``(mem, old)``: memory as if the ``ev`` lanes ran one at a time
+    in ascending order, as the numpy reference's loop runs them, and
+    ``old[t]`` the word lane t's atomic read.  Lane t reads only its own
+    register row, and before it writes it, so every operand comes from the
+    registers the instruction started with.  Per address the last lane's
+    value is the one memory keeps.  STG and EXCH store ``b``; ATOMADD's lane
+    t reads the word plus the ``b`` of the earlier lanes on its address
+    (int32 wraps, so the order of the sum does not matter); EXCH's reads the
+    ``b`` of the nearest earlier lane on its address.  CAS has no closed
+    form: a loop over the lanes carries only the word each lane's address
+    holds, never the memory or the registers.  Memory is written once, as
+    a one-hot select, not a scatter (see :func:`_put`); one-hot sums also
+    stand in for gathers, which ran slower on a TPU v5e.
+    """
+    W, M = ev.shape[0], mem.shape[0]
+    lane = jnp.arange(W, dtype=I32)
+    addr = (regs[:, jnp.clip(s0, 0)] + imm) % M
+    b = regs[:, jnp.clip(s1, 0)]
+    c = regs[:, jnp.clip(s2, 0)]
+    at = addr[:, None] == jnp.arange(M)                   # [W, M]
+    word = jnp.sum(jnp.where(at, mem, 0), axis=1, dtype=I32)  # mem[addr]
+    same = ev[None, :] & (addr[:, None] == addr[None, :])  # [t, t']
+    before = same & (lane[None, :] < lane[:, None])
+    last = ev & ~jnp.any(same & (lane[None, :] > lane[:, None]), axis=1)
+
+    nearest = jnp.max(jnp.where(before, lane[None, :], -1), axis=1)
+    old_exch = jnp.where(
+        nearest >= 0,
+        jnp.sum(jnp.where(lane[None, :] == nearest[:, None], b, 0), axis=1,
+                dtype=I32),
+        word)
+    old_add = word + jnp.sum(jnp.where(before, b, 0), axis=1, dtype=I32)
+
+    def chain(t, carry):          # CAS: lane t swaps on what lanes < t left
+        cur, olds = carry
+        o = cur[t]
+        cur = jnp.where(same[:, t], jnp.where(o == b[t], c[t], o), cur)
+        return cur, jnp.where(lane == t, o, olds)
+    cur, old_cas = lax.fori_loop(0, W, chain, (word, word))
+
+    is_cas, is_add = op == Op.ATOMCAS, op >= Op.ATOMADD
+    old = jnp.where(is_cas, old_cas, jnp.where(is_add, old_add, old_exch))
+    new = jnp.where(is_cas, cur, jnp.where(is_add, old_add + b, b))
+    hit = ev[:, None] & at
+    kept = jnp.sum(jnp.where(hit & last[:, None], new[:, None], 0), axis=0,
+                   dtype=I32)
+    return jnp.where(jnp.any(hit, axis=0), kept, mem), old
+
+
 # ---------------------------------------------------------------------------
 # the scheduler step
 # ---------------------------------------------------------------------------
@@ -428,35 +480,13 @@ def _step(s: HanoiState, program: jax.Array, cfg: MachineConfig,
                 addr = (R[:, jnp.clip(s0, 0)] + imm) % cfg.mem_size
                 return set_pc(upd_reg(st, st.mem[addr]), pc + 1)
 
-            def h_stg(st):
-                def body(t, mem):
-                    a = (R[t, jnp.clip(s0, 0)] + imm) % cfg.mem_size
-                    return jnp.where(ev[t],
-                                     _put(mem, a, R[t, jnp.clip(s1, 0)]), mem)
-                return set_pc(st._replace(
-                    mem=lax.fori_loop(0, W, body, st.mem)), pc + 1)
-
-            def _atomic(kind):
-                def h(st):
-                    def body(t, carry):
-                        mem, regs = carry
-                        a = (regs[t, jnp.clip(s0, 0)] + imm) % cfg.mem_size
-                        old = mem[a]
-                        bval = regs[t, jnp.clip(s1, 0)]
-                        if kind == "cas":
-                            cval = regs[t, jnp.clip(s2, 0)]
-                            new = jnp.where(old == bval, cval, old)
-                        elif kind == "exch":
-                            new = bval
-                        else:
-                            new = old + bval
-                        mem = jnp.where(ev[t], _put(mem, a, new), mem)
-                        row = _put(regs[t], jnp.clip(dst, 0), old)
-                        regs = jnp.where(ev[t], _put(regs, t, row), regs)
-                        return mem, regs
-                    mem, regs = lax.fori_loop(0, W, body, (st.mem, st.regs))
-                    return set_pc(st._replace(mem=mem, regs=regs), pc + 1)
-                return h
+            def h_mem(st):
+                mem, old = _lane_serial_mem(op, st.mem, R, ev, s0, s1, s2,
+                                            imm)
+                wr = ev & (op != Op.STG)
+                return set_pc(st._replace(mem=mem, regs=jnp.where(
+                    wr[:, None] & (jnp.arange(cfg.n_regs) == jnp.clip(dst, 0)),
+                    old[:, None], st.regs)), pc + 1)
 
             handlers = [
                 h_fallthrough,                      # NOP
@@ -471,8 +501,7 @@ def _step(s: HanoiState, program: jax.Array, cfg: MachineConfig,
                 _bin(lambda a, b: a | b),           # OR
                 _bin(lambda a, b: a ^ b),           # XOR
                 h_shl, h_shr, h_isetp, h_laneid,
-                h_ldg, h_stg,
-                _atomic("cas"), _atomic("exch"), _atomic("add"),
+                h_ldg, h_mem,       # STG and the atomics clip to this branch
             ]
             return lax.switch(jnp.clip(op, 0, len(handlers) - 1), handlers, s)
 

@@ -161,8 +161,9 @@ class ArchState:
                         M[a] = R[t, s2]
                 elif op == Op.ATOMEXCH:
                     M[a] = R[t, s1]
-                else:  # ATOMADD
-                    M[a] = _I32(int(old) + int(R[t, s1]))
+                else:  # ATOMADD, wrapping in int32 as IADD does
+                    M[a] = _I32((int(old) + int(R[t, s1]) + 2**31) % 2**32
+                                - 2**31)
                 R[t, dst] = old
         else:
             raise ValueError(f"alu cannot handle op {Op(op).name}")

@@ -351,3 +351,118 @@ def test_batch_copies_to_the_host_once_and_assembles_numpy(monkeypatch):
             assert not np.shares_memory(getattr(a, f), getattr(host, f)), f
             for b in results[i + 1:]:
                 assert not np.shares_memory(getattr(a, f), getattr(b, f)), f
+
+
+# ---------------------------------------------------------------------------
+# STG and the atomics: lanes collide on addresses, in ascending lane order
+# ---------------------------------------------------------------------------
+
+MEM_CFG = MachineConfig(n_threads=32, mem_size=64, max_steps=16)
+MEM_OPS = ("STG", "ATOMADD", "ATOMEXCH", "ATOMCAS")
+LANE = np.arange(32)
+I32_MAX = 2**31 - 1
+
+# scenario -> (address register R0, b in R1, c in R2, active lanes in R3,
+# imm, the atomics' destination register, memory words set before the run)
+MEM_CASES = {
+    "one_address": (np.full(32, 5), LANE * 7 + 1, LANE + 100,
+                    np.ones(32), 0, 4, {5: 36}),
+    "two_interleaved": (9 + 20 * (LANE % 2), LANE // 2, LANE // 2 + 1,
+                        np.ones(32), 0, 4, {9: 0, 29: 3}),
+    "predicated_gaps": (LANE % 3, LANE // 3, LANE // 3 + 1,
+                        LANE % 4 != 1, 2, 4, {2: 0, 3: 0, 4: 1}),
+    "negative_wrapping": ((LANE % 4) * 64 - 200 + LANE % 2, LANE - 16,
+                          16 - LANE, np.ones(32), 70, 4, {}),
+    "int32_overflow": (LANE % 2, I32_MAX - LANE, -I32_MAX + LANE,
+                       np.ones(32), 0, 4, {0: I32_MAX - 5, 1: -I32_MAX}),
+    "dst_is_s0": (LANE % 3 + 4, LANE % 5, LANE + 1, np.ones(32), 0, 0,
+                  {4: 0, 5: 2}),
+    "dst_is_s1": (LANE % 3 + 4, LANE % 5, LANE + 1, np.ones(32), 0, 1,
+                  {4: 0, 5: 2}),
+    # lane 2k compares with k and swaps in k + 1 on one word, lane 2k + 1
+    # on another: each chain runs only as long as every earlier swap did,
+    # until lane 20 (compare 99) breaks the first
+    "cas_chain": (11 + 3 * (LANE % 2), np.where(LANE == 20, 99, LANE // 2),
+                  LANE // 2 + 1, np.ones(32), 0, 4, {11: 0, 14: 0}),
+}
+
+
+def _mem_request(op, case, variant):
+    """One warp whose only work is one ``op`` under a collision scenario;
+    ``variant`` picks the memory image the scenario's words are set in."""
+    from repro.core.asm import assemble
+    from repro.engine import SimRequest
+    addr, b, c, active, imm, d, words = MEM_CASES[case]
+    operands = f"[R0+{imm}], R1"
+    text = {"STG": f"STG {operands}",
+            "ATOMCAS": f"ATOMCAS R{d}, {operands}, R2"}.get(
+                op, f"{op} R{d}, {operands}")
+    prog = assemble(f"    ISETP.NE P0, R3, 0\n    @P0 {text}\n    EXIT\n")
+    regs = np.zeros((32, MEM_CFG.n_regs), np.int64)
+    regs[:, 0], regs[:, 1], regs[:, 2], regs[:, 3] = addr, b, c, active
+    regs[:, 5:] = LANE[:, None] * 3 - 7            # registers no op names
+    rng = np.random.default_rng([MEM_OPS.index(op), variant])
+    mem = (np.arange(64) * 3 - 50 if variant == 0
+           else rng.integers(-2**31, 2**31, 64))
+    for a, v in words.items():
+        mem[a] = v
+    return SimRequest(program=prog, cfg=MEM_CFG,
+                      init_regs=regs.astype(np.int32),
+                      init_mem=mem.astype(np.int32))
+
+
+@pytest.fixture(scope="module")
+def mem_batch():
+    """Every (op, scenario) case under two memory images: 64 warps of four
+    opcodes in one vmapped lane-step batch, each request with its result."""
+    from repro.engine.adapters import _run_hanoi_jax_batch
+    keys = [(op, case, v) for v in (0, 1) for op in MEM_OPS
+            for case in MEM_CASES]
+    assert len(keys) == 64
+    reqs = [_mem_request(*k) for k in keys]
+    got = _run_hanoi_jax_batch(reqs)
+    return {k: (r, g) for k, r, g in zip(keys, reqs, got)}
+
+
+@pytest.mark.parametrize("case", list(MEM_CASES))
+@pytest.mark.parametrize("op", MEM_OPS)
+def test_memory_op_collisions_match_numpy(op, case, mem_batch):
+    """STG and the atomics keep the numpy reference's lane-serial order —
+    lanes in ascending order, only the executing ones — when lanes collide
+    on addresses, as a single warp and as one row of a 64-row batch."""
+    for v in (0, 1):
+        req, batched = mem_batch[(op, case, v)]
+        want = Simulator("hanoi").run(req)
+        assert want.status.name == "OK"
+        _assert_same_result(Simulator("hanoi_jax").run(req), want)
+        _assert_same_result(batched, want)
+
+
+def test_lane_step_has_no_serial_lane_loop():
+    """The batched lane step's only loops are the step loop and at most
+    one more: STG and the atomics update memory lane-parallel, not in a
+    loop over the 32 lanes whose carry holds the memory or registers (a
+    static ``fori_loop`` shows up as ``scan``)."""
+    import jax
+    import jax.numpy as jnp
+    from repro.engine.adapters import _jitted_batch_runner
+    cfg = MachineConfig(n_threads=32)
+    n, L, W = 4, 32, cfg.n_threads
+    sds = jax.ShapeDtypeStruct
+    jaxpr = jax.make_jaxpr(_jitted_batch_runner(cfg, True))(
+        sds((n, L, 8), jnp.int32), sds((n, L), jnp.bool_),
+        sds((n, W, cfg.n_regs), jnp.int32), sds((n, cfg.mem_size), jnp.int32),
+        sds((n, W), jnp.int32))
+
+    def loops(jx):
+        found = []
+        for eqn in jx.eqns:
+            if eqn.primitive.name in ("while", "scan"):
+                found.append(eqn.primitive.name)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                found += loops(sub)
+        return found
+
+    found = loops(jaxpr.jaxpr)
+    assert "while" in found
+    assert len(found) <= 2, found
