@@ -9,7 +9,8 @@ All measurements flow through the unified ``repro.engine`` API:
            including the BFSD outlier (+SIMD-utilization gain);
 * SS IX-A — hardware storage cost vs. a SIMT-Stack (432 B / ~43% claim);
 * engine throughput: vectorized JAX mechanism (vmap ``run_batch``) vs. the
-  numpy reference mechanism, warps/second.
+  numpy reference mechanism, warps/second;
+* warp-level SIMD utilization of every registered mechanism on BFSD.
 """
 from __future__ import annotations
 
@@ -21,7 +22,7 @@ import numpy as np
 from repro.core import MachineConfig, hardware_cost_bytes
 from repro.core.programs import make_suite
 from repro.core.timing import TimingConfig
-from repro.engine import CompareReport, Simulator
+from repro.engine import CompareReport, Simulator, available_mechanisms
 
 CFG = MachineConfig(n_threads=32, mem_size=256, max_steps=60_000)
 PAIR = ("hanoi", "turing_oracle")
@@ -94,6 +95,20 @@ def hw_cost_rows() -> list[dict]:
     return out
 
 
+def mechanism_utilization_rows() -> list[dict]:
+    """Warp-level SIMD utilization of each control-flow mechanism on the
+    divergence-heavy BFS benchmark, computed through the unified engine
+    API."""
+    cfg = MachineConfig(n_threads=8, mem_size=64, max_steps=8192)
+    bench = next(b for b in make_suite(cfg, datasets=1) if b.name == "BFSD")
+    rows = []
+    for mech in available_mechanisms():
+        res = _SIM.run(bench, cfg, mechanism=mech)
+        rows.append({"mechanism": mech, "utilization": res.utilization,
+                     "steps": res.steps, "status": res.status.value})
+    return rows
+
+
 def engine_throughput(n_warps: int = 32, reps: int = 3) -> dict:
     """Vectorized JAX mechanism vs numpy mechanism, warps/second.
 
@@ -143,6 +158,10 @@ def main() -> None:
               f"simt={r['simt_stack_bytes']}B saving={r['saving_frac']:.1%}")
     print("== engine throughput ==")
     print(f"  {engine_throughput()}")
+    print("== SIMD utilization per mechanism (BFSD, repro.engine) ==")
+    for r in mechanism_utilization_rows():
+        print(f"  {r['mechanism']:14s} util={r['utilization']:6.1%} "
+              f"steps={r['steps']:5d} status={r['status']}")
 
 
 if __name__ == "__main__":

@@ -1,10 +1,7 @@
 """Benchmark aggregator: one section per paper table/figure.
 
 Prints ``name,us_per_call,derived`` CSV rows (harness contract), then the
-human-readable sections.  The multi-pod dry-run / roofline tables are produced
-separately by ``python -m repro.launch.dryrun --all`` +
-``python -m benchmarks.roofline`` (they need the 512-device flag set at
-process start).
+human-readable sections.
 
 ``--engine-api`` runs only a tiny end-to-end smoke of the unified
 ``repro.engine`` API (one ``Simulator.compare`` call on a reduced machine) —
@@ -99,23 +96,13 @@ def main(argv: list[str] | None = None) -> None:
 
     rows += engine_api_smoke()
 
-    from benchmarks import bench_kernels as bk
     t0 = time.perf_counter()
-    census = bk.tile_census_rows()
-    dt = (time.perf_counter() - t0) * 1e6
-    for r in census:
-        rows.append((f"tiles[{r['case']}]", dt / len(census),
-                     f"kept={r['flops_kept_frac']:.3f};"
-                     f"partial={r['partial']};empty={r['empty']}"))
-    t0 = time.perf_counter()
-    mech = bk.mechanism_utilization_rows()
+    mech = bcf.mechanism_utilization_rows()
     dt = (time.perf_counter() - t0) * 1e6
     for r in mech:
         rows.append((f"mech_util[{r['mechanism']}]", dt / len(mech),
                      f"util={r['utilization']:.3f};"
                      f"steps={r['steps']}"))
-    for r in bk.kernel_timing_rows():
-        rows.append((f"kernel[{r['kernel']}]", r["us"], ""))
 
     print("name,us_per_call,derived")
     for name, us, derived in rows:

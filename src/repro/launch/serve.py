@@ -1,12 +1,7 @@
-"""Serving driver: batched prefill + decode with KV/recurrent caches,
-plus the control-flow *simulation service* endpoint.
+"""Control-flow simulation service CLI: serve simulation requests, or
+replay an archive of them.
 
-Greedy-decodes a batch of prompts on a smoke config (CPU) or the production
-mesh (TPU).  Prefill is teacher-forced through ``decode_step`` position by
-position for windowed/recurrent caches' ring semantics — the compiled decode
-step is the same function the decode_32k / long_500k dry-run cells lower.
-
-``serve_simulations`` is the second endpoint: a thin client of
+``serve_simulations`` and ``--mode sim`` (the default) are thin clients of
 ``repro.service.SimulationService`` — the queue-fed, coalescing, sharded
 simulation service.  Requests are admitted one by one, coalesced by
 execution signature, routed to the vmap-batched JAX ``batch_runner`` when
@@ -21,8 +16,6 @@ paper's Fig 9 from the durable archive instead of a live run.  With
 live service are picked up each poll and folded into a rolling aggregate.
 
 Usage:
-  python -m repro.launch.serve --arch rwkv6-3b --batch 4 --prompt-len 16 \\
-      --gen-len 32
   python -m repro.launch.serve --mode sim --mechanism hanoi_jax --batch 64
   python -m repro.launch.serve --mode sim --mechanism volta_itps --batch 16 \\
       --workers 4 --max-batch 32 --max-wait-ms 5 --archive-dir sim-archive
@@ -42,49 +35,7 @@ from __future__ import annotations
 import argparse
 import time
 
-import jax
-import jax.numpy as jnp
 import numpy as np
-
-from repro.configs import get_config
-from repro.models import cache_struct, decode_step, init_params, model_struct
-from repro.models.base import init_params as init_cache
-
-
-def serve(arch: str, *, smoke: bool = True, batch: int = 4,
-          prompt_len: int = 16, gen_len: int = 32, max_len: int = 256,
-          seed: int = 0, greedy: bool = True) -> dict:
-    cfg = get_config(arch, smoke=smoke)
-    assert cfg.is_decoder and cfg.frontend == "token", \
-        f"{arch} is not a token decoder"
-    params = init_params(model_struct(cfg), jax.random.PRNGKey(seed))
-    caches = [init_cache(cs, jax.random.PRNGKey(1))
-              for cs in cache_struct(cfg, batch, max_len)]
-
-    rng = np.random.default_rng(seed)
-    prompts = rng.integers(2, cfg.vocab_size,
-                           size=(batch, prompt_len)).astype(np.int32)
-
-    dec = jax.jit(lambda p, c, t, i: decode_step(p, cfg, c, t, i))
-    tokens = jnp.asarray(prompts)
-    out_tokens = []
-    t0 = time.time()
-    logits = None
-    for i in range(prompt_len + gen_len - 1):
-        if i < prompt_len:
-            tok = tokens[:, i:i + 1]
-        else:
-            tok = out_tokens[-1]
-        logits, caches = dec(params, caches, tok,
-                             jnp.asarray(i, jnp.int32))
-        if i >= prompt_len - 1:
-            nxt = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
-            out_tokens.append(nxt)
-    dt = time.time() - t0
-    gen = np.concatenate([np.asarray(t) for t in out_tokens], axis=1)
-    steps = prompt_len + gen_len - 1
-    return {"generated": gen, "steps": steps, "wall_s": dt,
-            "tokens_per_s": batch * steps / dt}
 
 
 def serve_simulations(requests, *, mechanism: str = "hanoi_jax",
@@ -259,11 +210,9 @@ def _replay_main(args) -> None:
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--mode", choices=["lm", "sim", "replay"], default="lm")
-    ap.add_argument("--arch", default="llama3.2-1b")
-    ap.add_argument("--batch", type=int, default=4)
-    ap.add_argument("--prompt-len", type=int, default=16)
-    ap.add_argument("--gen-len", type=int, default=32)
+    ap.add_argument("--mode", choices=["sim", "replay"], default="sim")
+    ap.add_argument("--batch", type=int, default=4,
+                    help="[sim] number of requests to serve")
     ap.add_argument("--mechanism", default="hanoi_jax",
                     help="[sim] control-flow mechanism to serve with "
                          "(any registered name, e.g. volta_itps)")
@@ -333,15 +282,8 @@ def main():
     install_jax_cache()
     if args.mode == "sim":
         _sim_main(args)
-        return
-    if args.mode == "replay":
+    else:
         _replay_main(args)
-        return
-    res = serve(args.arch, batch=args.batch, prompt_len=args.prompt_len,
-                gen_len=args.gen_len)
-    print(f"[serve] generated {res['generated'].shape} tokens in "
-          f"{res['wall_s']:.2f}s ({res['tokens_per_s']:.1f} tok/s)")
-    print(res["generated"][:, :10])
 
 
 if __name__ == "__main__":

@@ -8,8 +8,10 @@ registry (``iter_mechanisms()``), so any future ``@register_mechanism``
 plugin — DARM-style melding, decoupled control flow, ... — is held to the
 bar automatically:
 
-* over the shared benchmark suite (race-free members) and over random
-  ``tests/progen.py`` programs, final ``regs`` / ``mem`` / ``finished``
+* over the shared benchmark suite (race-free members), at 8 lanes and at
+  the paper's 32 (where a full mask is ``0xFFFFFFFF``, ``-1`` as int32),
+  and over random ``tests/progen.py`` programs (8 lanes), final
+  ``regs`` / ``mem`` / ``finished``
   must agree with ``simt_stack`` wherever BOTH mechanisms report
   ``SimStatus.OK``.  Register comparison excludes ``BMOV B->R`` spill
   destinations: those hold microarchitectural reconvergence masks on the
@@ -41,6 +43,10 @@ from tests.progen import CHECK_REGS, COUNTER_CELL, W, make_program
 
 CFG = MachineConfig(n_threads=8, mem_size=64, max_steps=20_000)
 SUITE = make_suite(CFG, datasets=1)
+CFG32 = MachineConfig(n_threads=32, mem_size=256, max_steps=20_000)
+# the suite half runs at both widths; 32 is the paper's warp width and
+# that of every benchmark cell
+WIDTHS = {"w8": (CFG, SUITE), "w32": (CFG32, make_suite(CFG32, datasets=1))}
 SIM = Simulator("simt_stack")
 
 ALL_MECHANISMS = [m.name for m in iter_mechanisms()]
@@ -76,12 +82,16 @@ def _assert_state_agrees(res, base, *, check_regs=None, program=None,
 # shared benchmark suite: everyone vs the pre-Volta baseline
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("mech", ALL_MECHANISMS)
-@pytest.mark.parametrize("bench", [b for b in SUITE if b.race_free],
-                         ids=lambda b: b.name)
-def test_suite_conformance(bench, mech):
-    base = SIM.run(bench, CFG, mechanism="simt_stack")
-    res = SIM.run(bench, CFG, mechanism=mech)
+# at 32 lanes the baseline is not compared with itself
+@pytest.mark.parametrize("cfg, bench, mech", [
+    pytest.param(cfg, bench, mech, id=f"{w}-{bench.name}-{mech}")
+    for w, (cfg, suite) in WIDTHS.items()
+    for bench in suite if bench.race_free
+    for mech in ALL_MECHANISMS
+    if not (w == "w32" and mech == "simt_stack")])
+def test_suite_conformance(cfg, bench, mech):
+    base = SIM.run(bench, cfg, mechanism="simt_stack")
+    res = SIM.run(bench, cfg, mechanism=mech)
     if not (base.ok and res.ok):
         pytest.skip(f"not comparable: {mech}={res.status.value} "
                     f"baseline={base.status.value}")
@@ -90,13 +100,15 @@ def test_suite_conformance(bench, mech):
 
 
 @pytest.mark.parametrize("mech", ALL_MECHANISMS)
-def test_suite_mechanisms_complete_race_free_programs(mech):
+@pytest.mark.parametrize("width", list(WIDTHS))
+def test_suite_mechanisms_complete_race_free_programs(width, mech):
     """No registered mechanism may be vacuously conformant: every one must
     actually finish the deadlock-free structured suite."""
-    for bench in SUITE:
+    cfg, suite = WIDTHS[width]
+    for bench in suite:
         if not bench.race_free:
             continue
-        res = SIM.run(bench, CFG, mechanism=mech)
+        res = SIM.run(bench, cfg, mechanism=mech)
         assert res.ok, f"{mech} failed {bench.name}: {res.status.value}"
 
 

@@ -1,8 +1,8 @@
 """Dual-Path baseline (paper SS X comparison): what it can and cannot do.
 
 The paper argues Hanoi beats Dual-Path because Dual-Path cannot support the
-Turing control-flow instructions.  Reproducing the comparison turned up a
-sharper picture than the test author first assumed (see EXPERIMENTS.md):
+Turing control-flow instructions.  The tests below show a sharper picture
+than that claim alone:
 
 * Dual-Path's two-path interleaving does NOT rescue the spinlock — the
   critical-section path is *born at* the IPDom reconvergence point, so it
@@ -107,8 +107,8 @@ def test_warpsync_dual_path_interleaves_hanoi_serializes():
 def test_break_is_a_latency_vs_utilization_tradeoff():
     """BFSW (loop + BREAK early exit): Hanoi's escaped threads run ahead in
     small groups; Dual-Path's forced-IPDom merge packs lanes.  Results agree;
-    utilizations differ in opposite directions per program — recorded in
-    EXPERIMENTS.md rather than asserted as a universal ordering."""
+    utilizations differ in opposite directions per program, so the test
+    holds both to (0, 1] and asserts no ordering between them."""
     suite = [b for b in make_suite(CFG, datasets=1)
              if b.name.startswith("BFSW")]
     assert suite
