@@ -437,7 +437,7 @@ def _run_lane_step(reqs: Sequence[SimRequest]):
     packed, hash-consed and padded to their :func:`batch_class` by
     repeating the first.  Returns ``(host, inv, progs, trace, lane_s,
     compile_s)``: the batch's host states (unique rows first), ``inv[i]``
-    the row of ``reqs[i]``, every request's packed program, the device's
+    the row of ``reqs[i]``, the batch's packed programs, the device's
     ``(trace_pc, trace_mask)`` (the rest of the device states is freed on
     return), the ``sim.lane_step`` seconds and the fresh compile's seconds
     (``None`` on a cache hit).
@@ -464,7 +464,7 @@ def _run_lane_step(reqs: Sequence[SimRequest]):
     if obs.enabled():
         # rows past len(first) repeat row 0: padding, not useful work
         _count_lane_step(cfg, host.steps[:len(first)], host.fuel)
-    return (host, inv, arrays[0], (states.trace_pc, states.trace_mask),
+    return (host, inv, rows[0], (states.trace_pc, states.trace_mask),
             lane.seconds, compile_s)
 
 

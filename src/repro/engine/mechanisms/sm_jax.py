@@ -11,7 +11,7 @@ land end to end, in two fused device programs:
    _run_lane_step`: one row per distinct warp, padded to its
    :func:`~repro.engine.adapters.batch_class`, programs to their
    :func:`~repro.engine.adapters.padded_len` class);
-2. **scheduler phase** — one ``lax.while_loop`` steps an entire N-warp SM:
+2. **scheduler phase** — one ``lax.scan`` steps an entire N-warp SM:
    per-warp trace cursors, completion times and memory-blocked flags are
    vectors, warp readiness is a boolean vector, and the issue policy is an
    ``argmin`` over the :func:`repro.timing.policies.priority_keys` vector
@@ -109,7 +109,7 @@ def _out_capacity(n: int) -> int:
 
 def _cell_scheduler(n_warps: int, out_cap: int, policy_id: int,
                     lat_tab: np.ndarray, mem_tab: np.ndarray):
-    """One-cell scheduler: a single ``lax.while_loop`` over issue slots.
+    """One-cell scheduler: a single ``lax.scan`` over issue slots.
 
     State is entirely vectors over the cell's warps; each iteration issues
     exactly one instruction (after an optional event hop over an idle gap),
@@ -120,8 +120,8 @@ def _cell_scheduler(n_warps: int, out_cap: int, policy_id: int,
 
     I32 = jnp.int32
     BIG = jnp.int32(np.iinfo(np.int32).max)
-    LAT = jnp.asarray(lat_tab)
-    ISMEM = jnp.asarray(mem_tab)
+    # an opcode's issue latency and blocks-on-memory flag in one word
+    WORD = jnp.asarray(lat_tab.astype(np.int32) * 2 + mem_tab)
     w_ids = jnp.arange(n_warps, dtype=jnp.int32)
     NOP = jnp.int32(int(Op.NOP))
 
@@ -135,20 +135,29 @@ def _cell_scheduler(n_warps: int, out_cap: int, policy_id: int,
         return w_ids                                   # oldest_first
 
     # the name is the XLA module's in profiler traces (``jit_schedule``)
-    def schedule(warp_map, trace_n, ops, trace_pc_u, trace_mask_u):
-        # warp_map[w] -> row in the hash-consed trace buffers (shared,
-        # un-vmapped operands): replicated warps read one trace copy.
-        # A fixed-length scan over issue slots (not a while_loop with
-        # output rings): scan's stacked ys are dense per-slot stores,
-        # which XLA lowers far better than per-iteration batched
-        # dynamic-update scatters.  Slots past ``total`` are masked
-        # no-ops (``out_cap`` is the grid's padded slot budget).
+    def schedule(warp_map, trace_n, ops_u, trace_pc_u, trace_mask_u):
+        # warp_map[w] -> row in the hash-consed trace buffers and in
+        # ``ops_u``, the rows' opcode columns (shared, un-vmapped
+        # operands): replicated warps read one copy.  A fixed-length scan
+        # over issue slots; slots past ``total`` are masked no-ops
+        # (``out_cap`` is the grid's padded slot budget).
         total = jnp.sum(trace_n)
-        L = ops.shape[1]
+        n_uniq, T = trace_pc_u.shape
+        L = ops_u.shape[1]
+        if n_warps * T > np.iinfo(np.int32).max:
+            raise ValueError(f"{n_warps} warps x {T} trace steps overflow "
+                             f"the scheduler's int32 slot words")
+        # every trace position's latency word, once per row before the
+        # scan, so the scan body holds one indexed op: the lookup below
+        pc = trace_pc_u
+        op = jnp.where((pc >= 0) & (pc < L),
+                       ops_u[jnp.arange(n_uniq)[:, None],
+                             jnp.clip(pc, 0, L - 1)], NOP)
+        slot_word = WORD[jnp.clip(op, 0, _N_OPS - 1)]
 
         def step(st, _):
             (idx, t_ready, t_mem, in_order, cycle, issued, last, cursor,
-             busy, istall, sstall, mstall, tinstr) = st
+             busy, istall, sstall, mstall) = st
             active = issued < total
             pending = idx < trace_n
             earliest = jnp.where(pending,
@@ -168,20 +177,18 @@ def _cell_scheduler(n_warps: int, out_cap: int, policy_id: int,
             sel = jnp.argmin(jnp.where(ready, priority(last, cursor),
                                        BIG)).astype(jnp.int32)
             n_ready = jnp.sum(ready).astype(jnp.int32)
-            pc = trace_pc_u[warp_map[sel], idx[sel]]
-            mask = trace_mask_u[warp_map[sel], idx[sel]]
-            op = jnp.where((pc >= 0) & (pc < L),
-                           ops[sel, jnp.clip(pc, 0, L - 1)], NOP)
-            op = jnp.clip(op, 0, _N_OPS - 1)
-            t_ready = jnp.where(active, t_ready.at[sel].set(cycle + LAT[op]),
-                                t_ready)
-            t_mem = jnp.where(active, t_mem.at[sel].set(ISMEM[op]), t_mem)
-            in_order = jnp.where(active, in_order.at[sel].set(cycle + 1),
-                                 in_order)
-            idx = jnp.where(active, idx.at[sel].add(1), idx)
+            # one-hot sums and selects stand in for gathers from and
+            # scatters into the warp vectors (``hanoi._put``'s idiom):
+            # batched indexed ops cost per cell on a TPU v5e
+            one = w_ids == sel
+            hit = one & active
+            t = jnp.sum(jnp.where(one, idx, I32(0)))
+            word = slot_word[jnp.sum(jnp.where(one, warp_map, I32(0))), t]
+            t_ready = jnp.where(hit, cycle + (word >> 1), t_ready)
+            t_mem = jnp.where(hit, (word & 1) == 1, t_mem)
+            in_order = jnp.where(hit, cycle + 1, in_order)
+            idx = idx + hit.astype(jnp.int32)
             act32 = active.astype(jnp.int32)
-            tinstr = tinstr + act32 * lax.population_count(mask).astype(
-                jnp.int32)
             busy = busy + act32
             # port contention: a warp left ready in the issued cycle
             istall = istall + act32 * (n_ready > 1).astype(jnp.int32)
@@ -189,12 +196,9 @@ def _cell_scheduler(n_warps: int, out_cap: int, policy_id: int,
                 last = jnp.where(active, sel, last)
             if policy_id == _RR:
                 cursor = jnp.where(active, (sel + 1) % n_warps, cursor)
-            out = (jnp.where(active, sel, I32(-1)),
-                   jnp.where(active, pc, I32(-1)),
-                   jnp.where(active, mask, jnp.uint32(0)))
             return (idx, t_ready, t_mem, in_order, cycle + act32,
                     issued + act32, last, cursor, busy, istall, sstall,
-                    mstall, tinstr), out
+                    mstall), jnp.where(active, sel * T + t, I32(-1))
 
         init = (jnp.zeros(n_warps, jnp.int32),          # idx
                 jnp.zeros(n_warps, jnp.int32),          # t_ready
@@ -203,11 +207,19 @@ def _cell_scheduler(n_warps: int, out_cap: int, policy_id: int,
                 I32(0), I32(0),                         # cycle, issued
                 I32(0), I32(0),                         # last (GTO init 0),
                                                         # cursor
-                I32(0), I32(0), I32(0), I32(0), I32(0))  # busy + stalls +
-                                                         # tinstr
-        st, (ow, opc, om) = lax.scan(step, init, None, length=out_cap)
+                I32(0), I32(0), I32(0), I32(0))         # busy + stalls
+        st, slot = lax.scan(step, init, None, length=out_cap)
         (idx, t_ready, t_mem, in_order, cycle, issued, last, cursor,
-         busy, istall, sstall, mstall, tinstr) = st
+         busy, istall, sstall, mstall) = st
+        # the SM trace from each slot's (warp, trace position), after the
+        # scan; idle slots read (-1, -1, 0)
+        on = slot >= 0
+        w, t = jnp.divmod(jnp.maximum(slot, 0), T)
+        row = warp_map[w]
+        ow = jnp.where(on, w, I32(-1))
+        opc = jnp.where(on, trace_pc_u[row, t], I32(-1))
+        om = jnp.where(on, trace_mask_u[row, t], jnp.uint32(0))
+        tinstr = jnp.sum(lax.population_count(om).astype(jnp.int32))
         return ow, opc, om, issued, cycle, busy, istall, sstall, mstall, \
             tinstr
 
@@ -237,13 +249,13 @@ def _compiled_grid_scheduler(n_cells: int, n_warps: int, n_uniq: int,
         atomic_latency=atom, scoreboard=False))
     fn = jax.jit(jax.vmap(_cell_scheduler(n_warps, out_cap, policy_id,
                                           lat_tab, mem_tab),
-                          in_axes=(0, 0, 0, None, None)))
+                          in_axes=(0, 0, None, None, None)))
     sds = jax.ShapeDtypeStruct
     with obs.span("sim.compile") as timed:
         compiled = fn.lower(
             sds((n_cells, n_warps), jnp.int32),           # warp_map
             sds((n_cells, n_warps), jnp.int32),           # trace_n
-            sds((n_cells, n_warps, prog_len), jnp.int32),  # opcode columns
+            sds((n_uniq, prog_len), jnp.int32),           # opcode columns
             sds((n_uniq, trace_cap), jnp.int32),          # hash-consed traces
             sds((n_uniq, trace_cap), jnp.uint32)).compile()
     _SCHED_CACHE[key] = compiled
@@ -316,7 +328,7 @@ def run_cells(cells: Sequence[Sequence[SimRequest]], *,
             trace_n = host.trace_n[inv].reshape(C, N).astype(np.int32)
             scheduled = bool(record) and int(trace_n.max(initial=0)) > 0
             if scheduled:
-                ops = progs[:, :, F_OP].reshape(C, N, L)
+                ops_u = progs[:, :, F_OP]
                 out_cap = _out_capacity(int(trace_n.sum(axis=1).max()))
         if scheduled:
             # phase 2: the whole grid through one compiled vmapped
@@ -331,7 +343,7 @@ def run_cells(cells: Sequence[Sequence[SimRequest]], *,
             total_compile += sched_compile_s or 0.0
             with obs.span("sim.schedule") as sched_span:
                 out = sched(jnp.asarray(warp_map), jnp.asarray(trace_n),
-                            jnp.asarray(ops), dev_pc, dev_mask)
+                            jnp.asarray(ops_u), dev_pc, dev_mask)
                 out = [np.asarray(x) for x in jax.block_until_ready(out)]
             exec_s += sched_span.seconds
             ow, opc, om, out_n, cycles, busy, istall, sstall, mstall, \
@@ -437,7 +449,7 @@ def _run_sm_jax_batch(reqs: Sequence[SimRequest]) -> list[SimResult]:
     tags=("sm", "multi-warp", "composite", "vectorized"),
     description="per-SM model as one jit(vmap) lane-parallel program: "
                 "warps run on the cached hanoi_jax batch executable, the "
-                "SM scheduler is a lax.while_loop with the issue policy "
+                "SM scheduler is a lax.scan with the issue policy "
                 "as an argmin over a priority vector (meta: sm_warps, "
                 "sm_inner in {hanoi, hanoi_jax}, sm_policy); SM traces "
                 "bit-identical to sm_interleave")

@@ -92,11 +92,11 @@ def test_sm_grid_scheduler_compiles_for_v5e(sds, no_persistent_cache):
     lat, is_mem = _latency_tables(CycleConfig(scoreboard=False))
     fn = jax.jit(jax.vmap(_cell_scheduler(N_WARPS, OUT_CAP, _GTO, lat,
                                           is_mem),
-                          in_axes=(0, 0, 0, None, None)))
+                          in_axes=(0, 0, None, None, None)))
     compiled = fn.lower(
         sds((N_CELLS, N_WARPS), jnp.int32),
         sds((N_CELLS, N_WARPS), jnp.int32),
-        sds((N_CELLS, N_WARPS, PAD_LEN), jnp.int32),
+        sds((N_ROWS, PAD_LEN), jnp.int32),
         sds((N_ROWS, CFG.max_steps), jnp.int32),
         sds((N_ROWS, CFG.max_steps), jnp.uint32)).compile()
     assert 0 < _device_bytes(compiled) < HBM_BYTES

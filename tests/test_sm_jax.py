@@ -19,11 +19,14 @@ Acceptance contract:
   sequence and raises on unsized iterables, and the service's warp-level
   stats agree with the façade's cell width for 3-D ndarray stacks.
 """
+import re
+
 import numpy as np
 import pytest
 
 from repro.archive import ArchiveReader, Replayer
 from repro.core import MachineConfig
+from repro.core.isa import Op
 from repro.core.programs import make_suite
 from repro.engine import RotatingJsonlSink, SimRequest, Simulator
 from repro.engine.mechanisms.sm import (DEFAULT_WARPS, per_warp_programs,
@@ -155,6 +158,75 @@ def test_sm_jax_matches_interleave_on_progen(policy):
     for a, b in zip(jax_res, py_res):
         assert a.status == b.status
         _assert_sm_equal(a.meta["sm"], b.meta["sm"])
+
+
+@pytest.mark.parametrize("policy", POLICY_NAMES)
+def test_sm_jax_grid_matches_interleave_per_cell(policy):
+    """One grid of replicated, mixed and distinct cells whose trace
+    volumes differ 36-fold (903 against 25 slots), on 6 unique rows padded
+    to a batch class of 8: every cell's schedule equals the Python
+    interleaver's on that cell alone."""
+    from repro.engine.mechanisms.sm_jax import run_cells
+    names = [("LUD0", "LUD0", "LUD0"), ("HIST0", "DIAMOND", "HIST0"),
+             ("HOTS0", "RBFS0", "SLOCK")]
+    cells = [[SimRequest(program=BENCH[n].program, cfg=CFG,
+                         init_mem=BENCH[n].init_mem, name=n) for n in cell]
+             for cell in names]
+    sms = run_cells(cells, policy=policy)
+    for sm, cell in zip(sms, names):
+        ref = SIM.run_sm([BENCH[n] for n in cell], CFG, inner="hanoi",
+                         policy=policy)
+        _assert_sm_equal(sm, ref)
+    assert sms[0].steps > 8 * sms[1].steps
+
+
+@pytest.mark.parametrize("policy", POLICY_NAMES)
+def test_scheduler_issues_a_pc_outside_the_program_as_nop(policy):
+    """No program traces a pc outside ``[0, L)``, so the latency table's
+    NOP path is driven here with made-up traces: the compiled scheduler
+    equals ``schedule_cycle`` on them, a padding row left unread."""
+    from repro.engine.mechanisms.sm_jax import (_POLICY_IDS,
+                                                _compiled_grid_scheduler)
+    from repro.timing.sm_model import schedule_cycle
+    L, T, lat = 8, 16, (2, 7, 30, 41)           # ALU, control, memory, atomic
+    ops_u = np.array([[Op.LDG, Op.IADD, Op.BRA, Op.ATOMADD, Op.STG, Op.MOV,
+                       Op.EXIT, Op.LDG]] * 4, np.int32)
+    ops_u[1] = np.roll(ops_u[0], 3)
+    ops_u[2] = np.roll(ops_u[0], 5)             # row 3 pads with row 0
+    pcs = [[0, -1, 7, L, 3, 1, L + 5, 2, 4, 0], [-2, 2, 3, 5, 0, L],
+           [6, 99, 1, 4, 7, 7, -1, 0, 3, 5, 2, 6]]
+    trace_pc = np.full((4, T), -1, np.int32)
+    trace_mask = np.zeros((4, T), np.uint32)
+    rng = np.random.default_rng(19)
+    for u, row in enumerate(pcs + pcs[:1]):
+        trace_pc[u, :len(row)] = row
+        trace_mask[u, :len(row)] = rng.integers(1, 1 << 32, len(row))
+    warp_map = np.array([[0, 1, 2], [2, 2, 0]], np.int32)
+    trace_n = np.array([[len(pcs[u]) for u in cell] for cell in warp_map],
+                       np.int32)
+    compiled, _ = _compiled_grid_scheduler(2, 3, 4, T, L, 256,
+                                           _POLICY_IDS[policy], lat)
+    ow, opc, om, issued, cycles, busy, istall, sstall, mstall, tinstr = (
+        np.asarray(x) for x in compiled(warp_map, trace_n, ops_u,
+                                        trace_pc, trace_mask))
+    ccfg = CycleConfig(alu_latency=lat[0], control_latency=lat[1],
+                       memory_latency=lat[2], atomic_latency=lat[3],
+                       scoreboard=False)
+    for c, cell in enumerate(warp_map):
+        ref = schedule_cycle(
+            [list(zip(trace_pc[u, :len(pcs[u])].tolist(),
+                      trace_mask[u, :len(pcs[u])].tolist())) for u in cell],
+            [ops_u[u] for u in cell], policy, ccfg)
+        n = int(issued[c])
+        assert list(zip(ow[c, :n].tolist(), opc[c, :n].tolist(),
+                        om[c, :n].tolist())) == ref.order
+        assert (ow[c, n:] == -1).all() and (opc[c, n:] == -1).all()
+        assert (om[c, n:] == 0).all()
+        assert (int(cycles[c]), int(tinstr[c]), int(busy[c]),
+                int(istall[c]), int(sstall[c]), int(mstall[c])) == (
+            ref.cycles, ref.thread_instructions, ref.busy_cycles,
+            ref.issue_stall_cycles, ref.scoreboard_stall_cycles,
+            ref.memory_stall_cycles)
 
 
 def test_run_sm_sm_jax_heterogeneous_and_ndarray_cells():
@@ -337,6 +409,45 @@ def test_scheduler_program_keeps_its_module_name():
         2, 2, 8, 64, 32, 256, _POLICY_IDS["greedy_then_oldest"],
         (2, 1, 30, 40))
     assert compiled.as_text().startswith("HloModule jit_schedule,")
+
+
+def _hlo_ops(hlo: str, op: str, within: str | None = None) -> int:
+    """How many ``op`` instructions the HLO module text holds, or only
+    those of computation ``within`` and the computations it calls."""
+    comps = dict(re.findall(r"^(?:ENTRY )?%([\w.\-]+) .*?\{\n(.*?)^\}",
+                            hlo, re.M | re.S))
+    names = list(comps) if within is None else [within]
+    seen: set = set()
+    while names:
+        name = names.pop()
+        if name in seen:
+            continue
+        seen.add(name)
+        if within is not None:
+            for ref in re.findall(r"\b(?:calls|to_apply|body|condition|"
+                                  r"branch_computations)=(\{[^}]*\}|%[\w.\-]+)",
+                                  comps[name]):
+                names += re.findall(r"%([\w.\-]+)", ref)
+    return sum(len(re.findall(rf"\s{op}\(", comps[n])) for n in seen)
+
+
+@pytest.mark.parametrize("policy", POLICY_NAMES)
+def test_scheduler_scan_body_has_no_scatter_and_one_gather(policy):
+    """The scan body updates the issued warp by one-hot selects and reads
+    one precomputed latency word: no scatter, at most one gather (batched
+    indexed ops cost per cell on a TPU v5e).  The SM trace columns are
+    gathered after the scan, outside the loop."""
+    from repro.engine.mechanisms.sm_jax import (_POLICY_IDS,
+                                                _compiled_grid_scheduler)
+    compiled, _ = _compiled_grid_scheduler(
+        3, 4, 8, 64, 32, 256, _POLICY_IDS[policy], (2, 1, 30, 40))
+    hlo = compiled.as_text()
+    (body,) = re.findall(r"\swhile\(.*?\bbody=%([\w.\-]+)", hlo)
+    assert _hlo_ops(hlo, "scatter") == 0
+    assert _hlo_ops(hlo, "gather", within=body) <= 1
+    # warp_map[w], trace_pc_u[row, t] and trace_mask_u[row, t]
+    assert _hlo_ops(hlo, "gather") - _hlo_ops(hlo, "gather",
+                                                within=body) >= 3
 
 
 # ---------------------------------------------------------------------------
