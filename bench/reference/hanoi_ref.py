@@ -153,8 +153,9 @@ class _Arch:
                         M[a] = R[t, s2]
                 elif op == ATOMEXCH:
                     M[a] = R[t, s1]
-                else:
-                    M[a] = _I32(int(old) + int(R[t, s1]))
+                else:  # ATOMADD wraps in int32, as a 32-bit atomic does
+                    M[a] = _I32((int(old) + int(R[t, s1]) + 2**31) % 2**32
+                                - 2**31)
                 R[t, dst] = old
         else:
             raise ValueError(f"no ALU semantics for opcode {op}")

@@ -5,13 +5,13 @@ without the chip, and print what the compiler says they need.
 
 For each cell of ``BENCHMARK.json`` it builds unit 1 of seed 0 with the
 cell's own generator, works out the shapes the timed entry will compile
-(the lane step's batch and padding class; for grids the scheduler's cells,
-unique rows and slot capacity, from the plain reference's trace lengths),
-compiles each program for one chip of a described ``v5e:2x2`` topology and
-prints its ``memory_analysis``.  Nothing runs: this proves shapes and
-memory, not results or times.  Run it by hand before a chip call that
-changes shapes; it is not a test (the repository's compile tests own the
-topology fixture).
+(the lane step's batch class of distinct rows and padding class; for grids
+the scheduler's cells, unique rows and slot capacity, from the plain
+reference's trace lengths), compiles each program for one chip of a
+described ``v5e:2x2`` topology and prints its ``memory_analysis``.  Nothing
+runs: this proves shapes and memory, not results or times.  Run it by hand
+before a chip call that changes shapes; it is not a test (the repository's
+compile tests own the topology fixture).
 """
 import os
 import sys
@@ -37,25 +37,31 @@ def _bytes(compiled) -> dict:
             "code": m.generated_code_size_in_bytes}
 
 
+def _rows(reqs, cfg, L) -> int:
+    """The lane step's batch for ``reqs``: its distinct rows' class."""
+    from repro.engine.adapters import _batch_arrays, _dedupe_rows, batch_class
+    first, _ = _dedupe_rows(*_batch_arrays(reqs, cfg, L))
+    return batch_class(len(first))
+
+
 def shapes(mix: Mix, unit) -> list:
     """``(program, statics)`` the timed entry compiles for ``unit``."""
     from repro.core.isa import MachineConfig
-    from repro.engine.adapters import _batch_arrays, padded_len
-    from repro.engine.mechanisms.sm_jax import (_batch_class, _dedupe_rows,
-                                                _out_capacity)
+    from repro.engine.adapters import padded_len
+    from repro.engine.mechanisms.sm_jax import _out_capacity
     cfg = MachineConfig(**mix.machine)
     if not unit.grid:
+        # one lane-step batch per padding class, as ``hanoi_jax`` runs them
         groups: dict = {}
         for req in unit.requests:
-            key = (tuple(req.bsync_skip_pcs),
-                   padded_len(int(req.program.shape[0])))
-            groups[key] = groups.get(key, 0) + 1
-        return [("lane", (cfg, n, L)) for (_, L), n in groups.items()]
+            groups.setdefault(padded_len(int(req.program.shape[0])),
+                              []).append(req)
+        return [("lane", (cfg, _rows(reqs, cfg, L), L))
+                for L, reqs in groups.items()]
     reqs = [mix.request(w, skips=False, name="rehearse")
             for cell in unit.cells for w in cell]
     L = padded_len(max(int(r.program.shape[0]) for r in reqs))
-    first, _ = _dedupe_rows(*_batch_arrays(reqs, cfg, L))
-    n_uniq = _batch_class(len(first))
+    n_uniq = _rows(reqs, cfg, L)
     checker = Checker(mix)
     per_cell = [sum(checker.reference(w).steps for w in cell)
                 for cell in unit.cells]
@@ -84,11 +90,13 @@ def compile_for_v5e(kind, statics, one_chip):
     from repro.timing import CycleConfig
     cells, warps, n_uniq, T, L, out_cap = statics
     lat, is_mem = _latency_tables(CycleConfig(scoreboard=False))
+    # the unique rows' opcode columns and traces are shared by every cell,
+    # as ``sm_jax._compiled_grid_scheduler`` compiles them
     fn = jax.jit(jax.vmap(_cell_scheduler(warps, out_cap, _GTO, lat, is_mem),
-                          in_axes=(0, 0, 0, None, None)))
+                          in_axes=(0, 0, None, None, None)))
     return fn.lower(sds((cells, warps), jnp.int32),
                     sds((cells, warps), jnp.int32),
-                    sds((cells, warps, L), jnp.int32),
+                    sds((n_uniq, L), jnp.int32),
                     sds((n_uniq, T), jnp.int32),
                     sds((n_uniq, T), jnp.uint32)).compile()
 

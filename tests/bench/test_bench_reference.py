@@ -78,6 +78,35 @@ def test_hanoi_reference_matches_on_random_programs(features):
     assert checked >= 8
 
 
+def test_hanoi_reference_atomadd_wraps_past_int32():
+    """ATOMADDs whose sums cross 2**31 both ways, lanes colliding on two
+    words: the copy wraps in int32 as numpy ``hanoi`` and ``hanoi_jax``
+    do."""
+    from repro.core.asm import assemble
+    from repro.core.isa import MachineConfig
+    from repro.engine import SimRequest, Simulator
+    cfg = MachineConfig(n_threads=32, mem_size=64, max_steps=16)
+    top, lane = 2**31 - 1, np.arange(32)
+    prog = assemble("    ATOMADD R4, [R0+0], R1\n    EXIT\n")
+    regs = np.zeros((32, cfg.n_regs), np.int64)
+    regs[:, 0] = lane % 2
+    regs[:, 1] = np.where(lane % 2, -top + lane, top - lane)
+    regs = regs.astype(np.int32)
+    mem = np.arange(64, dtype=np.int32)
+    mem[0], mem[1] = top - 5, -top
+    got = run_warp(prog, _machine(cfg), regs=regs, mem=mem)
+    _same(interp.run_hanoi(prog, cfg, init_regs=regs, init_mem=mem), got,
+          cfg)
+    jax_got = Simulator("hanoi_jax").run(SimRequest(
+        program=prog, cfg=cfg, init_regs=regs, init_mem=mem))
+    assert np.array_equal(np.asarray(jax_got.mem), got.mem)
+    assert np.array_equal(np.asarray(jax_got.regs), got.regs)
+    for word in (0, 1):         # each word's lanes summed, wrapped once
+        total = int(mem[word]) + int(regs[word::2, 1].astype(np.int64).sum())
+        assert not -2**31 <= total < 2**31
+        assert got.mem[word] == (total + 2**31) % 2**32 - 2**31
+
+
 def test_sm_reference_matches_the_host_scheduler():
     from repro.timing import CycleConfig, schedule_cycle
     from repro.core.isa import MachineConfig
